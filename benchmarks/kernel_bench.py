@@ -22,6 +22,7 @@ from bench_util import append_run
 from repro.configs.base import HashMemConfig
 from repro.core import hashmap
 from repro.core.introspect import count_scatters
+from repro.launch.compile_cache import enable_compile_cache
 
 VMEM_BYTES = 128 * 1024 * 1024  # v5e VMEM per core
 
@@ -223,6 +224,7 @@ def main():
                     help="JSON output path (implies --json); "
                          "default BENCH_kernels.json")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.out is not None:
         args.json = True
     args.out = args.out or "BENCH_kernels.json"

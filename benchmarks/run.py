@@ -23,6 +23,9 @@ def _emit(name, us, derived):
 def main() -> None:
     from benchmarks import fig4_buckets, fig5_cpu_baselines, fig6_hashmem
     from benchmarks import kernel_bench
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     for r in fig4_buckets.run(n_words=30_000):
         _emit(r["name"], "",

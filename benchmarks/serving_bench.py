@@ -18,11 +18,11 @@ YCSB-style loadgen over the SAME request stream in several modes:
     whole-tick megakernel (ONE shard_map for probe+delete+insert, the
     engine default) with two-pass skew-aware routing; fused rows carry
     ``route_cap_*`` telemetry showing the routed ICI capacity tracking the
-    measured key skew instead of the Q_local worst case.  When the process
-    has fewer than N jax devices, the mesh rows run in a CHILD process
-    with --xla_force_host_platform_device_count=N — forcing host devices
-    in THIS process would split the CPU for the host-shard rows too and
-    poison their trajectory against single-device prior runs.
+    measured key skew instead of the Q_local worst case.  When a CPU
+    process has fewer than N jax devices, the mesh rows run in a CHILD
+    process with --xla_force_host_platform_device_count=N — forcing host
+    devices in THIS process would split the CPU for the host-shard rows
+    too and poison their trajectory against single-device prior runs.
 
 The PR-3 acceptance bar: at 64 concurrent requests the coalesced engine
 sustains >= 5x the ops/sec of the per-request baseline.  The ISSUE-6
@@ -43,7 +43,12 @@ import time
 
 from bench_util import append_run
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import build_ycsb_engine
+
+# these rows time the host-side engine on CPU; the reference probe keeps
+# them comparable with the earlier runs in BENCH_serving.json
+BACKEND = "ref"
 
 
 def _ratio(num: float, den: float) -> float:
@@ -59,7 +64,7 @@ def run_mode(*, coalesce, workloads, slots, shards, record_count,
     kw = dict(slots=slots, shards=shards, record_count=record_count,
               ops_per_request=ops_per_request, coalesce=coalesce,
               pipeline_depth=pipeline, mesh=mesh, fused_tick=fused,
-              trace=trace)
+              trace=trace, backend=BACKEND)
     # warmup: an identical engine REPLAYS the same request stream, so every
     # trace the timed runs will see — op-kind combos, pipeline stall/drain
     # shapes, and (fused mesh rows) the exact routed-capacity tuples baked
@@ -147,7 +152,8 @@ def trace_overhead_row(*, workloads, slots, shards, record_count,
     (lower-better, 1.0 = free), gated <=1.10x by tools/bench_check.py
     ABS_BARS."""
     kw = dict(slots=slots, shards=shards, record_count=record_count,
-              ops_per_request=ops_per_request, coalesce=True)
+              ops_per_request=ops_per_request, coalesce=True,
+              backend=BACKEND)
     walls = {False: float("inf"), True: float("inf")}
     total_ops = 0
     for rep in range(-1, max(repeats, 1)):      # rep -1 warms both paths
@@ -296,15 +302,21 @@ def _mesh_rows(num_shards: int, slots: int, kw: dict) -> list:
 
 
 def _mesh_block(args, kw: dict) -> list:
-    """Run the mesh rows inline when this process already has enough jax
-    devices; otherwise re-exec this script in a CHILD process with
+    """Run the mesh rows in this process when it has enough jax devices.
+    Otherwise, and only on CPU, re-exec this script in a CHILD process with
     --xla_force_host_platform_device_count (forcing host devices in the
     parent would split the CPU under the host-shard rows too, poisoning
-    their trajectory against single-device prior runs)."""
+    their trajectory against single-device prior runs).  A parent on an
+    accelerator holds it, so a child could not reach it: that fails."""
     import jax
     if jax.device_count() >= args.mesh_shards:
         return _mesh_rows(args.mesh_shards, args.slots, kw)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"--mesh-shards {args.mesh_shards} needs that many "
+            f"{jax.default_backend()} devices, have {jax.device_count()}")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count="
                         f"{args.mesh_shards}").strip()
@@ -346,6 +358,7 @@ def main():
     ap.add_argument("--mesh-rows-json", action="store_true",
                     help=argparse.SUPPRESS)  # child mode: emit mesh rows
     args = ap.parse_args()
+    enable_compile_cache()
     if args.out is not None:
         args.json = True
     args.out = args.out or "BENCH_serving.json"

@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import compat
-
 NEG_INF = -1e30
 
 
@@ -49,14 +47,14 @@ def init_pool(num_pages: int, page_tokens: int, kv_heads: int, head_dim: int,
 def _flat_index(axes: Sequence[str]) -> jax.Array:
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
 def _axes_size(axes: Sequence[str]) -> int:
     n = 1
     for a in axes:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 

@@ -40,12 +40,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import HashMemConfig
 from repro.core import hashmap
 from repro.core.hashing import EMPTY_KEY, HASH_FNS
-from repro.core.compat import shard_map
 
 U32 = jnp.uint32
 I32 = jnp.int32
@@ -147,11 +146,33 @@ def _local_bucket_fn(num_shards: int, shard_by: str = "mod"):
     return fn
 
 
+def _padded_len(n: int) -> int:
+    """``n`` rounded up to a sixteenth of its power-of-two octave (at most
+    6.25% padding), so batches of nearly equal size share one shape."""
+    step = 1 << max(n.bit_length() - 4, 0)
+    return -(-n // step) * step
+
+
 def insert_sharded(hm_stacked, keys, vals, cfg: HashMemConfig,
                    num_shards: int, max_grows: int = 4,
                    shard_by: str = "mod", max_splits: int = 256,
                    events: Optional[dict] = None):
-    """Host-level routed insert into the stacked shard pytree.
+    """``insert_shards`` over a stacked shard pytree: returns
+    (hm_stacked', ok (N,) bool, cfg')."""
+    shards = [jax.tree.map(lambda x, d=d: x[d], hm_stacked)
+              for d in range(num_shards)]
+    shards, ok, cfg2 = insert_shards(shards, keys, vals, cfg, num_shards,
+                                     max_grows=max_grows, shard_by=shard_by,
+                                     max_splits=max_splits, events=events)
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *shards), ok, cfg2
+
+
+def insert_shards(shards: list, keys, vals, cfg: HashMemConfig,
+                  num_shards: int, max_grows: int = 4,
+                  shard_by: str = "mod", max_splits: int = 256,
+                  events: Optional[dict] = None):
+    """Host-level routed insert into a list of per-shard HashMems (each
+    mutated where it lives: on a mesh, shard d stays on device d).
 
     Keys are routed to their owner shard (same global-hash split as
     build_sharded) and batch-inserted with the vectorized engine.  When a
@@ -170,7 +191,7 @@ def insert_sharded(hm_stacked, keys, vals, cfg: HashMemConfig,
         them.  A split the arena/chain bound refuses falls back to a
         synchronized grow() rebuild.
 
-    Returns (hm_stacked', ok (N,) bool, cfg').  cfg' differs from cfg after
+    Returns (shards', ok (N,) bool, cfg').  cfg' differs from cfg after
     growth/doubling; pass it to subsequent probe_sharded/insert_sharded
     calls.  ``events`` (optional dict) accumulates "splits"/"doublings"/
     "rebuilds" counts.
@@ -181,8 +202,7 @@ def insert_sharded(hm_stacked, keys, vals, cfg: HashMemConfig,
     owner = owner_of(keys, cfg, num_shards, shard_by)         # owner is
     owner_np = np.asarray(owner)                              # grow-invariant
     bfn = _local_bucket_fn(num_shards, shard_by)
-    shards = [jax.tree.map(lambda x, d=d: x[d], hm_stacked)
-              for d in range(num_shards)]
+    shards = list(shards)
     extendible = cfg.resize == "extendible"
 
     def _bump(k):
@@ -199,16 +219,22 @@ def insert_sharded(hm_stacked, keys, vals, cfg: HashMemConfig,
             idx = remaining[d]
             if idx.size == 0:
                 continue
-            kd, vd = keys[idx], vals[idx]
+            # pad to a shared length: the shards' batches differ by a few
+            # keys, and every eager op of the insert compiles per shape
+            m = _padded_len(idx.size)
+            pidx = np.concatenate([idx, np.zeros(m - idx.size, idx.dtype)])
+            kd, vd = keys[pidx], vals[pidx]
             bd = bfn(kd, shards[d].config)
-            hm_d, ok_d = hashmap.insert_with_buckets(shards[d], kd, vd, bd)
+            hm_d, ok_d = hashmap.insert_with_buckets(
+                shards[d], kd, vd, bd, jnp.asarray(np.arange(m) < idx.size))
             shards[d] = hm_d
-            ok_np = np.asarray(ok_d)
+            ok_np = np.asarray(ok_d)[:idx.size]
             ok[idx[ok_np]] = True
             remaining[d] = idx[~ok_np]
             if remaining[d].size:
                 any_fail = True
-                failed_buckets[d] = np.unique(np.asarray(bd)[~ok_np])
+                failed_buckets[d] = np.unique(
+                    np.asarray(bd)[:idx.size][~ok_np])
         if not any_fail or not cfg.auto_grow:
             break
         rebuild = not extendible
@@ -249,8 +275,42 @@ def insert_sharded(hm_stacked, keys, vals, cfg: HashMemConfig,
             grows += 1
             _bump("rebuilds")
 
-    hm_stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *shards)
-    return hm_stacked, jnp.asarray(ok), shards[0].config
+    return shards, jnp.asarray(ok), shards[0].config
+
+
+def place_shards(mesh, shards: list, axis: str = "model"):
+    """Stack per-shard HashMem pytrees onto the mesh, one shard per device
+    along ``axis``: shard d goes to device d as it is (no copy when it is
+    already there) and the stacked arrays are assembled from those pieces,
+    so the stack never materializes on a single device.  Done at table
+    build/growth time so the per-tick RLU calls start from device-resident
+    shards instead of resharding every call."""
+    devices = list(mesh.devices.reshape(-1))
+    assert len(shards) == len(devices), (len(shards), len(devices))
+    sharding = NamedSharding(mesh, P(axis))
+
+    def stack(*xs):
+        parts = [jax.device_put(x, d)[None] for x, d in zip(xs, devices)]
+        return jax.make_array_from_single_device_arrays(
+            (len(xs), *xs[0].shape), sharding, parts)
+    return jax.tree.map(stack, *shards)
+
+
+def local_shards(hm_stacked) -> list:
+    """Per-shard pytrees of a stacked shard pytree (leading dim =
+    num_shards).  Each shard of a placed pytree is read from the device
+    that holds it; an unplaced one is sliced."""
+    n = jax.tree.leaves(hm_stacked)[0].shape[0]
+
+    def piece(x, d):
+        shards = getattr(x, "addressable_shards", ())
+        if len(shards) == n:
+            for s in shards:
+                if s.index[0].start == d:
+                    return s.data[0]
+        return x[d]
+    return [jax.tree.map(lambda x, d=d: piece(x, d), hm_stacked)
+            for d in range(n)]
 
 
 def _local_probe(hm_local, queries, cfg: HashMemConfig, num_shards: int,
@@ -348,7 +408,7 @@ def _sharded_call(kind: str, mesh, cfg: HashMemConfig, axis: str,
         builder = {"probe": _probe_shard_fn, "delete": _delete_shard_fn,
                    "insert": _insert_shard_fn, "tick": _tick_shard_fn}[kind]
         shard_fn, n_in, n_out = builder(cfg, num_shards, axis, shard_by, cap)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(axis),) * n_in,
             out_specs=(P(axis),) * n_out,
@@ -593,7 +653,7 @@ def probe_replicated(mesh, hm, queries, cfg: HashMemConfig, axis: str = "data"):
     def shard_fn(hm_local, q_local):
         return hashmap.probe(hm_local, q_local, backend=cfg.backend)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(axis)),
         out_specs=(P(axis), P(axis)),

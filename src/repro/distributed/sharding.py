@@ -106,15 +106,6 @@ def stacked_hashmem_specs(hm_stacked, axis: str = "model"):
     return jax.tree.map(lambda _: P(axis), hm_stacked)
 
 
-def shard_stacked_hashmem(mesh: Mesh, hm_stacked, axis: str = "model"):
-    """Place a stacked shard pytree onto the mesh (one shard per device on
-    ``axis``).  Done once at table build/growth time so the per-tick RLU
-    calls (probe_sharded / delete_sharded / insert_mesh) start from
-    device-resident shards instead of resharding host arrays every call."""
-    return jax.device_put(
-        hm_stacked, named(mesh, stacked_hashmem_specs(hm_stacked, axis)))
-
-
 class ShardCtx:
     """Activation sharding constraints threaded through the model.
 

@@ -4,9 +4,9 @@ All probe entry points take the unified PageStore's interleaved (P, S, 2)
 pool — one page fetch per chain step serves both the key compare and the
 value readout.  Page schedules may carry interior -1 holes (fingerprint-
 filtered pages); the Pallas wrappers derive a forward-filled fetch index so
-those steps cost no row activation.  ``interpret`` defaults to True off-TPU
-(this container validates the kernel bodies in interpret mode; on a real
-v5e the same calls lower to Mosaic).
+those steps cost no row activation.  With ``interpret`` left at None the
+platform a call is lowered for picks the path: the Pallas interpreter on
+CPU, Mosaic on TPU (kernels/probe_common.py).
 
 These kernels never see the bucket directory: extendible-mode probes
 resolve their page schedule through the same bucket_head gather as rebuild

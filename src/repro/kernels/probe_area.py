@@ -15,95 +15,51 @@ per chain step; each strip compares the key lane and latches the matching
 value lane of the SAME row.  This preserves the paper's area/perf contrast:
 same single-activation I/O, serialized compare schedule.
 
-Same grid/O contract as probe_perf.
+Same grid/O contract as probe_perf (probe_common.py); each strip is a
+``pl.ds`` slice of the resident row, read straight from VMEM.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-U32 = jnp.uint32
-LINE = 128
+from repro.kernels.probe_common import (NO_SLOT, as_i32, first_match, latch,
+                                        probe_call, row_view, step_page)
+
 STRIP = 128
 
 
-def _make_kernel(strip: int):
-    def _kernel(pages_ref, fetch_ref, queries_ref, pool_ref, out_ref):
+def _make_kernel(strip: int, n_strips: int):
+    def _kernel(pages_ref, fetch_ref, queries_ref, pool_ref, out_ref,
+                hit_ref):
         del fetch_ref   # consumed by the BlockSpec index maps only
-        c = pl.program_id(1)
-        q = pl.program_id(0)
-
-        @pl.when(c == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        page = pages_ref[q, c]
-        query = queries_ref[q]
+        page = step_page(pages_ref)
+        query = queries_ref[pl.program_id(0)]
         valid = page >= 0
-        kv = pool_ref[...]                                   # (1, S, 2): one activation
-        keys_row = kv[0, :, 0]                               # (S,) uint32
-        vals_row = kv[0, :, 1]
-        S = keys_row.shape[0]
-        n_strips = S // strip
 
         def body(i, carry):
-            found, val, slot = carry
-            krow = jax.lax.dynamic_slice_in_dim(keys_row, i * strip, strip)
-            vrow = jax.lax.dynamic_slice_in_dim(vals_row, i * strip, strip)
-            match = (krow == query) & valid
-            any_m = jnp.any(match)
-            iota = jax.lax.broadcasted_iota(jnp.int32, (strip,), 0)
-            s_local = jnp.min(jnp.where(match, iota, jnp.int32(2**30)))
-            v_local = jnp.max(jnp.where((iota == s_local) & match, vrow, U32(0)))
-            take = any_m & jnp.logical_not(found)               # element-serial latch
-            return (found | any_m,
-                    jnp.where(take, v_local, val),
-                    jnp.where(take, i * strip + s_local, slot))
+            slot, val = carry
+            start = pl.multiple_of(i * strip, strip)
+            strip_kv = as_i32(pool_ref[:, pl.ds(start, strip)])   # (2, strip)
+            s_local, v_local = first_match(strip_kv[0:1, :],
+                                           strip_kv[1:2, :], query, valid)
+            # element-serial latch: only the first matching strip counts
+            take = (slot == NO_SLOT) & (s_local != NO_SLOT)
+            return (jnp.where(take, start + s_local, slot),
+                    jnp.where(take, v_local, val))
 
-        found, val, slot = jax.lax.fori_loop(
-            0, n_strips, body, (jnp.bool_(False), U32(0), jnp.int32(0)))
-
-        already = out_ref[0, 1] > U32(0)
-
-        @pl.when(found & jnp.logical_not(already))
-        def _write():
-            out_ref[0, 0] = val
-            out_ref[0, 1] = U32(1)
-            out_ref[0, 2] = page.astype(U32)
-            out_ref[0, 3] = slot.astype(U32)
+        slot, val = jax.lax.fori_loop(0, n_strips, body,
+                                      (jnp.int32(NO_SLOT), jnp.int32(0)))
+        latch(out_ref, hit_ref, slot, val, page)
 
     return _kernel
 
 
 def probe_pages_area(pool, queries, pages, *, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    qn, C = pages.shape
-    P, S, _ = pool.shape
+    S = pool.shape[1]
     # full lane strips on real shapes; small test pages fall back to one strip
     strip = min(STRIP, S)
     assert S % strip == 0, "slots must be a multiple of the strip width"
-
-    from repro.kernels.ref import fill_fetch_pages
-    pages = pages.astype(jnp.int32)
-    fetch = fill_fetch_pages(pages)   # filtered steps re-open the resident row
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(qn, C),
-        in_specs=[
-            pl.BlockSpec((1, S, 2),
-                         lambda q, c, pages, fetch, queries: (fetch[q, c], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, LINE),
-                               lambda q, c, pages, fetch, queries: (q, 0)),
-    )
-    out = pl.pallas_call(
-        _make_kernel(strip),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((qn, LINE), U32),
-        interpret=interpret,
-    )(pages, fetch, queries.astype(U32), pool)
-    return out[:, 0], out[:, 1] > 0
+    return probe_call(_make_kernel(strip, S // strip), "hashmem_probe_area",
+                      queries, pages, (row_view(pool),), interpret)

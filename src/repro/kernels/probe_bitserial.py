@@ -9,14 +9,12 @@ TPU adaptation (DESIGN.md §2): bit-planes are packed 32-slots-per-uint32-word
 (layout.pack_bitplanes); the per-bit step is a single vector XOR+OR over the
 word lanes, so one grid step performs `key_bits` vector ops regardless of the
 number of slots — exactly the paper's b-cycle CAM scan.  The value readout
-comes from the unified PageStore's interleaved page row, but the BlockSpec
-selects ONLY its value lane ((1, S, 1) block at lane index 1) — the
-bit-serial layout keeps keys column-oriented, so the plane row IS the key
-activation and fetching the pool's key lane too would double the per-step
-row traffic for bytes the kernel never reads.  On TPU
-this wins over probe_perf only for sub-32-bit keys (b = 4/8/16, the paper's
-column widths); at b=32 the bit-parallel compare of probe_perf is strictly
-better.  The benchmark harness quantifies that crossover (EXPERIMENTS.md
+comes from the unified PageStore's page row, row 1 of the (P, 2, S) view
+(probe_common).  Mosaic tiles the second-minor axis by 8 or takes it whole,
+so the BlockSpec fetches the whole (2, S) page and the key row rides along
+unread.  On TPU this wins over probe_perf only for sub-32-bit keys
+(b = 4/8/16, the paper's column widths); at b=32 the bit-parallel compare
+of probe_perf is strictly better.  The benchmark harness quantifies that crossover (EXPERIMENTS.md
 §Perf).
 
 I/O: planes (P, b, W=S//32) u32 bit-planes, pool (P, S, 2) u32 interleaved
@@ -27,93 +25,49 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-U32 = jnp.uint32
-LINE = 128
+from repro.kernels.probe_common import (I32, INT_MIN, NO_SLOT, as_i32,
+                                        latch, probe_call, row_view,
+                                        step_page)
 
 
 def _make_kernel(key_bits: int):
-    def _kernel(pages_ref, fetch_ref, queries_ref, planes_ref, pool_ref,
-                out_ref):
+    def _kernel(pages_ref, fetch_ref, queries_ref, planes_ref, page_ref,
+                out_ref, hit_ref):
         del fetch_ref   # consumed by the BlockSpec index maps only
-        c = pl.program_id(1)
-        q = pl.program_id(0)
-
-        @pl.when(c == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        page = pages_ref[q, c]
-        query = queries_ref[q].astype(U32)
-        valid = page >= 0
-        W = planes_ref.shape[2]
-        S = W * 32
+        page = step_page(pages_ref)
+        query = queries_ref[pl.program_id(0)]
+        planes = as_i32(planes_ref[...])                     # (b, W)
+        W = planes.shape[1]
 
         # --- the bit-serial scan: key_bits steps, all slots in parallel ---
-        mismatch = jnp.zeros((1, W), U32)
+        mismatch = jnp.zeros((1, W), I32)
         for j in range(key_bits):                            # static unroll: b steps
-            qbit = (query >> U32(j)) & U32(1)
-            qword = jnp.where(qbit > 0, U32(0xFFFFFFFF), U32(0))
-            plane = planes_ref[0, j, :].reshape(1, W)
-            mismatch = mismatch | (plane ^ qword)
+            qword = -((query >> j) & 1)                      # 0 or all ones
+            mismatch = mismatch | (planes[j:j + 1, :] ^ qword)
         match_words = ~mismatch                              # (1, W)
 
         # --- one-time extraction (the RLU readout, not part of the b-scan) ---
-        bit_i = jax.lax.broadcasted_iota(jnp.int32, (W, 32), 1).astype(U32)
-        words = jnp.broadcast_to(match_words.reshape(W, 1), (W, 32))
-        bits = ((words >> bit_i) & U32(1)) > 0               # (W, 32) slot matches
-        match = bits.reshape(1, S) & valid
-        any_match = jnp.any(match)
-        slot_iota = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
-        slot = jnp.min(jnp.where(match, slot_iota, jnp.int32(2**30)))
-        onehot = (slot_iota == slot) & match
-        vals_row = pool_ref[...].reshape(1, S)               # value lane only
-        val = jnp.max(jnp.where(onehot, vals_row, U32(0)))
-
-        already = out_ref[0, 1] > U32(0)
-
-        @pl.when(any_match & jnp.logical_not(already))
-        def _write():
-            out_ref[0, 0] = val
-            out_ref[0, 1] = U32(1)
-            out_ref[0, 2] = page.astype(U32)
-            out_ref[0, 3] = slot.astype(U32)
+        # bit i of word w is slot 32*w + i: (32, W) tiles, no relayout
+        bit_i = jax.lax.broadcasted_iota(I32, (32, W), 0)
+        word_i = jax.lax.broadcasted_iota(I32, (32, W), 1)
+        bits = (jax.lax.shift_right_logical(
+            jnp.broadcast_to(match_words, (32, W)), bit_i) & 1) == 1
+        slot = jnp.min(jnp.where(bits & (page >= 0), word_i * 32 + bit_i,
+                                 NO_SLOT))
+        vals = as_i32(page_ref[...])[1:2, :]                 # value row
+        slot_iota = jax.lax.broadcasted_iota(I32, vals.shape, 1)
+        val = jnp.max(jnp.where(slot_iota == slot, vals, INT_MIN))
+        latch(out_ref, hit_ref, slot, val, page)
 
     return _kernel
 
 
 def probe_pages_bitserial(planes, pool, queries, pages, key_bits: int,
                           *, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    qn, C = pages.shape
     P, b, W = planes.shape
     assert b == key_bits
     S = pool.shape[1]
     assert S == W * 32
-
-    from repro.kernels.ref import fill_fetch_pages
-    pages = pages.astype(jnp.int32)
-    fetch = fill_fetch_pages(pages)   # filtered steps re-open the resident row
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(qn, C),
-        in_specs=[
-            pl.BlockSpec((1, b, W),
-                         lambda q, c, pages, fetch, queries: (fetch[q, c], 0, 0)),
-            # value lane only: block index 1 in the size-1 trailing dim
-            pl.BlockSpec((1, S, 1),
-                         lambda q, c, pages, fetch, queries: (fetch[q, c], 0, 1)),
-        ],
-        out_specs=pl.BlockSpec((1, LINE),
-                               lambda q, c, pages, fetch, queries: (q, 0)),
-    )
-    out = pl.pallas_call(
-        _make_kernel(key_bits),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((qn, LINE), U32),
-        interpret=interpret,
-    )(pages, fetch, queries.astype(U32), planes, pool)
-    return out[:, 0], out[:, 1] > 0
+    return probe_call(_make_kernel(key_bits), "hashmem_probe_bitserial",
+                      queries, pages, (planes, row_view(pool)), interpret)

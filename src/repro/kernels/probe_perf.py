@@ -16,83 +16,33 @@ bit-serial; see probe_bitserial for the faithful bit-serial variant).
 
 Grid: (Q, C) — C (chain position) iterates fastest and accumulates
 first-match results into a 128-lane output "cache line" per query, matching
-the paper's RLU returning the value padded to a cache line (§2.5).
-
-Output cache-line layout (uint32 lanes): [value, found, page, slot, 0...].
+the paper's RLU returning the value padded to a cache line (§2.5).  The
+grid, the (P, 2, S) row view of the pool, the cache line and the choice
+between Mosaic and the interpreter are shared with the other two kernels
+(probe_common.py).
 """
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-U32 = jnp.uint32
-LINE = 128  # output cache line width (lanes)
+from repro.kernels.probe_common import (as_i32, first_match, latch,
+                                        probe_call, row_view, step_page)
 
 
-def _kernel(pages_ref, fetch_ref, queries_ref, pool_ref, out_ref):
+def _kernel(pages_ref, fetch_ref, queries_ref, pool_ref, out_ref, hit_ref):
     del fetch_ref   # consumed by the BlockSpec index maps only
-    c = pl.program_id(1)
-    q = pl.program_id(0)
-
-    @pl.when(c == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    page = pages_ref[q, c]
-    query = queries_ref[q]
-    valid = page >= 0
-
-    kv = pool_ref[...]                                       # (1, S, 2) uint32
-    row = kv[..., 0]                                         # (1, S) keys
-    match = (row == query) & valid                           # element-parallel compare
-    any_match = jnp.any(match)
-
-    slot_iota = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
-    slot = jnp.min(jnp.where(match, slot_iota, jnp.int32(2**30)))
-    onehot = (slot_iota == slot) & match
-    val = jnp.max(jnp.where(onehot, kv[..., 1], U32(0)))     # same activated row
-
-    already = out_ref[0, 1] > U32(0)
-
-    @pl.when(any_match & jnp.logical_not(already))
-    def _write():
-        out_ref[0, 0] = val
-        out_ref[0, 1] = U32(1)
-        out_ref[0, 2] = page.astype(U32)
-        out_ref[0, 3] = slot.astype(U32)
+    page = step_page(pages_ref)
+    kv = as_i32(pool_ref[...])                    # (2, S): ONE activated row
+    # element-parallel compare of every key of the row; the value comes
+    # from the same activated row
+    slot, val = first_match(kv[0:1, :], kv[1:2, :], queries_ref[pl.program_id(0)],
+                            page >= 0)
+    latch(out_ref, hit_ref, slot, val, page)
 
 
 def probe_pages_perf(pool, queries, pages, *, interpret=None):
     """(values (Q,) u32, found (Q,) bool).  ``pool`` is the interleaved
-    (P, S, 2) page pool; see module docstring."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    from repro.kernels.ref import fill_fetch_pages
-    qn, C = pages.shape
-    P, S, _ = pool.shape
-    pages = pages.astype(jnp.int32)
-    # forward-filled fetch schedule: a filtered (-1) step repeats the last
-    # block index, so Pallas keeps the row resident instead of re-fetching
-    # (zero extra row activations; see ref.fill_fetch_pages)
-    fetch = fill_fetch_pages(pages)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,       # pages, fetch, queries
-        grid=(qn, C),
-        in_specs=[
-            # ONE row activation: keys AND values in a single page fetch
-            pl.BlockSpec((1, S, 2),
-                         lambda q, c, pages, fetch, queries: (fetch[q, c], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, LINE),
-                               lambda q, c, pages, fetch, queries: (q, 0)),
-    )
-    out = pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((qn, LINE), U32),
-        interpret=interpret,
-    )(pages, fetch, queries.astype(U32), pool)
-    return out[:, 0], out[:, 1] > 0
+    (P, S, 2) page pool; see module docstring.  ``interpret=None``: the
+    lowering platform decides (probe_common)."""
+    return probe_call(_kernel, "hashmem_probe_perf", queries, pages,
+                      (row_view(pool),), interpret)

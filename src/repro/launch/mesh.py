@@ -2,39 +2,22 @@
 
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state; the dry-run sets XLA_FLAGS before first jax use.
-
-``jax.sharding.AxisType`` only exists in newer jax releases; older ones
-(e.g. 0.4.x) neither expose it nor accept ``axis_types=`` in
-``jax.make_mesh``.  ``_make_mesh_compat`` papers over the difference so the
-same call sites work on both.
 """
 from __future__ import annotations
 
 import jax
 
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
 
-
-def _make_mesh_compat(shape, axes):
-    if _AXIS_TYPE is not None:
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(axes),
-                axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes):
+    """Mesh of ``shape`` over ``axes``, every axis Auto-sharded."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh_compat(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary (test-sized) mesh with the same axis semantics."""
-    return _make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_serving_mesh(num_shards: int = 0, axis: str = "model"):
@@ -44,4 +27,4 @@ def make_serving_mesh(num_shards: int = 0, axis: str = "model"):
     n = num_shards or len(jax.devices())
     assert n <= len(jax.devices()), \
         f"serving mesh wants {n} devices, have {len(jax.devices())}"
-    return _make_mesh_compat((n,), (axis,))
+    return make_mesh((n,), (axis,))

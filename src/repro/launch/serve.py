@@ -33,6 +33,7 @@ import numpy as np
 from repro.configs import ServeConfig, get_config, smoke_config
 from repro.configs.base import ShapeConfig
 from repro.core.paged_kv import PageTableManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.distributed import steps as dsteps
 from repro.launch.mesh import make_mesh
 from repro.models import model
@@ -130,7 +131,7 @@ def serve(cfg, mesh, *, batch=4, horizon=256, page_tokens=32, requests=8,
 
 def serve_kv(*, workloads="A", tenants=None, requests=64, slots=16,
              shards=1, record_count=1024, ops_per_request=4,
-             max_pending=0, tenant_slots=0, seed=0, backend="ref",
+             max_pending=0, tenant_slots=0, seed=0, backend="perf",
              mesh_shards=0, pipeline=1, fused_tick=None, verbose=True,
              trace_out=None, metrics_prom=None):
     """Thin driver over the multi-tenant KV serving engine: one tenant per
@@ -188,7 +189,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--horizon", type=int, default=256)
     ap.add_argument("--page-tokens", type=int, default=32)
-    ap.add_argument("--backend", default="ref",
+    ap.add_argument("--backend", default="perf",
                     choices=["ref", "perf", "area", "bitserial"])
     ap.add_argument("--mesh", type=int, nargs="*", default=None)
     ap.add_argument("--compact-chain-len", type=int, default=None,
@@ -223,6 +224,7 @@ def main():
                     help="(kv mode) write the Prometheus text exposition "
                          "of the run's metrics here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.mode == "kv":
         serve_kv(workloads=args.workloads, requests=args.requests,

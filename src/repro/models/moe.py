@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.layers import dense_init
-from repro.core.compat import shard_map
 
 
 def init(key, cfg, layer_ff=None):
@@ -202,7 +201,7 @@ def apply_ep(params, cfg, x, mesh, batch_axes=("data",), model_axis="model"):
     bspec = xspec if xspec else None
     sspec = model_axis if x.shape[1] % mesh.shape[model_axis] == 0 else None
     espec = baxes if baxes else None
-    y, aux, zl, dropped = shard_map(
+    y, aux, zl, dropped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P_(bspec, sspec, None),           # x: batch + seq sharded
                   P_(),                             # router (replicated)

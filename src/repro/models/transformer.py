@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core import paged_kv
 from repro.models import attention, mamba, mlp, moe, xlstm
 from repro.models.layers import norm_init, rms_norm
-from repro.core.compat import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +245,7 @@ def _paged_attn_sub(p_attn, cfg, h, state, block_table, pos, ctx):
 
         pool_spec = P(tuple(ba) + tuple(ca))     # grouped page layout
         bspec = P(ba if ba else None)
-        k_pool, v_pool, o = shard_map(
+        k_pool, v_pool, o = jax.shard_map(
             inner, mesh=ctx.mesh,
             in_specs=(pool_spec, pool_spec, bspec, bspec, bspec, bspec, bspec),
             out_specs=(pool_spec, pool_spec, bspec),

@@ -382,18 +382,21 @@ class _MeshShards:
 
     def __init__(self, eng: "ServingEngine", mesh, axis: str,
                  cfg: HashMemConfig, tables: Optional[list] = None):
-        from repro.distributed.sharding import shard_stacked_hashmem
-        self._place = lambda hm: shard_stacked_hashmem(mesh, hm, axis)
+        # shard d is built, grown and compacted on device d; the stacked
+        # pytree is assembled from the per-device pieces
+        self._place = lambda shards: rlu.place_shards(mesh, shards, axis)
         self.eng = eng
         self.mesh = mesh
         self.axis = axis
         self.num_shards = mesh.shape[axis]
         self.cfg = cfg
-        shards = list(tables) if tables is not None \
-            else [hashmap.create(cfg) for _ in range(self.num_shards)]
-        assert len(shards) == self.num_shards
-        self.hm_stacked = self._place(
-            jax.tree.map(lambda *xs: jnp.stack(xs), *shards))
+        if tables is None:
+            tables = []
+            for d in mesh.devices.reshape(-1):
+                with jax.default_device(d):
+                    tables.append(hashmap.create(cfg))
+        assert len(tables) == self.num_shards
+        self.hm_stacked = self._place(list(tables))
 
     @property
     def auto_grow(self) -> bool:
@@ -496,7 +499,7 @@ class _MeshShards:
 
     # -- slow paths --------------------------------------------------------
     def grow_insert(self, keys: np.ndarray, vals: np.ndarray):
-        """Drain-time PR_ERROR fallback: the repair runs in rlu.insert_sharded
+        """Drain-time PR_ERROR fallback: the repair runs in rlu.insert_shards
         — extendible splits are per-shard local (shape-preserving) and
         directory doublings are synchronized pointer copies; only a full
         grow() REBUILD (detected by num_pages, which doubling preserves)
@@ -504,12 +507,12 @@ class _MeshShards:
         (ok, [rebuilt shard ids] — all or none here, events)."""
         before = self.cfg.num_pages
         events: dict = {}
-        hm, ok, cfg2 = rlu.insert_sharded(
-            self.hm_stacked, jnp.asarray(keys), jnp.asarray(vals), self.cfg,
+        shards, ok, cfg2 = rlu.insert_shards(
+            self.shard_list(), jnp.asarray(keys), jnp.asarray(vals), self.cfg,
             self.num_shards, shard_by=self.eng.shard_by, events=events)
         grew = cfg2.num_pages != before
         self.cfg = cfg2
-        self.hm_stacked = self._place(hm)
+        self.hm_stacked = self._place(shards)
         return (np.asarray(ok),
                 list(range(self.num_shards)) if grew else [], events)
 
@@ -527,13 +530,11 @@ class _MeshShards:
                 shards[s] = hashmap.compact(hm, bucket_fn=bfn)
                 out.append(s)
         if out:
-            self.hm_stacked = self._place(
-                jax.tree.map(lambda *xs: jnp.stack(xs), *shards))
+            self.hm_stacked = self._place(shards)
         return out
 
     def shard_list(self) -> list:
-        return [jax.tree.map(lambda x, d=d: x[d], self.hm_stacked)
-                for d in range(self.num_shards)]
+        return rlu.local_shards(self.hm_stacked)
 
 
 # ---------------------------------------------------------------------------
@@ -630,7 +631,9 @@ class ServingEngine:
             self.tracer = Tracer() if trace else NULL_TRACER
         self._profile_window = None    # (start_tick, stop_tick, logdir)
         self._profiling = False
-        self._profiler_live = False
+        # a profiler start/stop that raised, as its repr: the run goes on
+        # untraced and the caller reads the failure here or in stats()
+        self.profiler_error: Optional[str] = None
         self.shard_by = shard_by
         self.coalesce = coalesce
         self.pad_pow2 = pad_pow2
@@ -643,8 +646,7 @@ class ServingEngine:
         self.metrics = metrics or MetricsCollector()
         if cfg is None and tables is None:
             cfg = HashMemConfig(num_buckets=256, slots_per_page=64,
-                                overflow_pages=256, max_chain=8,
-                                backend="ref")
+                                overflow_pages=256, max_chain=8)
         # shared jit-compiled call paths (module-level cache).  The insert
         # fast path is the fixed-arena hashmap.insert — PR_ERROR falls back
         # to the host-level grow loop at drain time.
@@ -1332,21 +1334,20 @@ class ServingEngine:
                             tick=self.ticks, logdir=logdir)
         try:
             jax.profiler.start_trace(logdir)
-            self._profiler_live = True
-        except Exception:                  # profiler backend unavailable
-            self._profiler_live = False
+        except Exception as e:             # profiler backend unavailable
+            self.profiler_error = repr(e)
+            self._profiling = False
+            self._profile_window = None
 
     def _stop_profiler(self):
         self._profiling = False
         self._profile_window = None
         self.tracer.instant("profiler_stop", tid=self._cur_lane,
                             tick=self.ticks)
-        if self._profiler_live:
-            self._profiler_live = False
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        try:
+            jax.profiler.stop_trace()
+        except Exception as e:
+            self.profiler_error = repr(e)
 
     # -- introspection -----------------------------------------------------
     def export_trace(self, path: str, **metadata) -> int:
@@ -1390,6 +1391,7 @@ class ServingEngine:
             "resize": self.backend.resize_mode,
             "compact_events": self.compact_events,
             "killed_requests": self.killed_requests,
+            "profiler_error": self.profiler_error,
             "occupancy": self.pool.occupancy(),
             "pending": len(self.pool.pending),
             "pipeline": {"depth": self.pipeline_depth,

@@ -26,6 +26,13 @@ from repro.serving.tenancy import Tenant
 DISTRIBUTIONS = ("zipfian", "uniform", "latest")
 
 
+def _cdf(p: np.ndarray) -> np.ndarray:
+    """``Generator.choice``'s own CDF of the probabilities ``p``."""
+    cdf = np.cumsum(p)
+    cdf /= cdf[-1]
+    return cdf
+
+
 @dataclass
 class WorkloadSpec:
     """One tenant's workload: a YCSB mix (or explicit op probabilities)
@@ -60,21 +67,29 @@ class LoadGen:
         self.kinds = list(self.mix)
         self.probs = np.asarray([self.mix[k] for k in self.kinds])
         self.probs = self.probs / self.probs.sum()
+        self._kind_cdf = _cdf(self.probs)
         self.insert_point = spec.record_count    # YCSB insertion counter
         self._zipf_n = 0
-        self._zipf_w = None
+        self._zipf_cdf = None
 
     # -- key choice --------------------------------------------------------
+    def _draw(self, cdf) -> int:
+        """Index drawn from a cached CDF: the same uniform draw and search
+        as ``Generator.choice(n, p=...)``, so streams are bit-identical to
+        it for a seed, without rebuilding the O(n) CDF on every op."""
+        return int(cdf.searchsorted(self.rng.random(), side="right"))
+
     def _zipf(self, n: int) -> int:
-        """Zipfian rank in [0, n).  The O(n) weight vector is rebuilt only
-        when the key range has grown ~25% past the cached size (inserts bump
+        """Zipfian rank in [0, n).  The O(n) CDF is rebuilt only when the
+        key range has grown ~25% past the cached size (inserts bump
         ``insert_point`` on every op in insert-bearing workloads); between
         rebuilds ranks are drawn over the cached prefix — the hot head,
         which is where a zipfian draw lands anyway."""
-        if self._zipf_w is None or n < self._zipf_n or n > self._zipf_n * 1.25:
+        if self._zipf_cdf is None or n < self._zipf_n \
+                or n > self._zipf_n * 1.25:
             self._zipf_n = n
-            self._zipf_w = zipfian_weights(n, self.spec.theta)
-        return min(int(self.rng.choice(self._zipf_n, p=self._zipf_w)), n - 1)
+            self._zipf_cdf = _cdf(zipfian_weights(n, self.spec.theta))
+        return min(self._draw(self._zipf_cdf), n - 1)
 
     def choose_key(self) -> int:
         n = max(self.insert_point, 1)
@@ -93,7 +108,7 @@ class LoadGen:
 
     # -- ops / requests ----------------------------------------------------
     def next_op(self) -> tuple:
-        kind = self.kinds[int(self.rng.choice(len(self.kinds), p=self.probs))]
+        kind = self.kinds[self._draw(self._kind_cdf)]
         val = int(self.rng.integers(1, 2**31))
         if kind == "read":
             return ("read", self.choose_key())
@@ -126,27 +141,38 @@ class LoadGen:
         return keys, vals
 
 
-def preload_engine(engine, gens: list) -> None:
-    """Run the load phase for every generator into the engine's shards."""
+def preload_engine(engine, gens: list) -> list:
+    """Run the load phase for every generator into the engine's shards;
+    returns the loaded ``(keys, vals)`` per generator."""
+    loaded = []
     for g in gens:
         keys, vals = g.preload_kv()
         engine.preload(keys, vals, tenant=g.tenant)
+        loaded.append((keys, vals))
+    return loaded
 
 
 def build_ycsb_engine(workloads, *, slots=16, shards=1, record_count=1024,
-                      ops_per_request=4, coalesce=True, backend="ref",
+                      ops_per_request=4, coalesce=True, backend="perf",
                       seed=0, max_pending=0, tenant_slots=0, metrics=None,
                       cfg=None, mesh=None, pipeline_depth=1,
-                      fused_tick=None, trace=None):
+                      fused_tick=None, trace=None, record_schedule=False,
+                      preload=True):
     """One preloaded engine + one (tenant, LoadGen) per YCSB workload letter
-    — the single assembly path shared by the serve.py kv CLI and
-    benchmarks/serving_bench.py, so both exercise identically-sized tables.
+    — the single assembly path shared by the serve.py kv CLI,
+    benchmarks/serving_bench.py and chip_smoke.py, so all exercise
+    identically-sized tables.  ``backend`` is the probe backend (it
+    overrides ``cfg.backend``).
     ``mesh``: route the shards through the RLU mesh path (one stacked table,
     one shard per device on the 'model' axis; ``shards`` is ignored).
     ``pipeline_depth``: multi-tick op pipelining (engine.py).
     ``fused_tick``: None = engine default (fused whole-tick megakernel on
     mesh+coalesce), False = per-phase shard_map calls.
+    ``preload=False`` leaves the load phase to the caller
+    (``preload_engine``).
     Returns (engine, [LoadGen, ...])."""
+    import dataclasses
+
     from repro.configs.base import HashMemConfig
     from repro.serving.engine import ServingEngine
     from repro.serving.tenancy import TenantRegistry
@@ -161,11 +187,13 @@ def build_ycsb_engine(workloads, *, slots=16, shards=1, record_count=1024,
     cfg = cfg or HashMemConfig(num_buckets=max(256, record_count // 16),
                                slots_per_page=64,
                                overflow_pages=max(256, record_count // 16),
-                               max_chain=8, backend=backend)
+                               max_chain=8)
+    cfg = dataclasses.replace(cfg, backend=backend)
     eng = ServingEngine(cfg, num_shards=shards, max_slots=slots,
                         max_pending=max_pending, tenants=reg,
                         metrics=metrics, coalesce=coalesce, mesh=mesh,
                         pipeline_depth=pipeline_depth, fused_tick=fused_tick,
-                        trace=trace)
-    preload_engine(eng, gens)
+                        trace=trace, record_schedule=record_schedule)
+    if preload:
+        preload_engine(eng, gens)
     return eng, gens

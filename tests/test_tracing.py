@@ -341,6 +341,9 @@ def test_profiler_backend_failure_is_survivable(tmp_path, monkeypatch):
     eng.submit(Request(ops=[("insert", 3, 4), ("read", 3)]))
     eng.run()                           # must not raise
     assert eng._profiling is False
+    # ... but the failure is not swallowed: the caller sees it
+    assert "no profiler backend" in eng.profiler_error
+    assert eng.stats()["profiler_error"] == eng.profiler_error
 
 
 # ---------------------------------------------------------------------------
