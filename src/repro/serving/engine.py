@@ -85,7 +85,7 @@ from repro.configs.base import HashMemConfig
 from repro.core import hashmap, rlu
 from repro.serving.metrics import MetricsCollector
 from repro.serving.tenancy import Tenant, TenantRegistry
-from repro.serving.tracing import NULL_TRACER, Tracer
+from repro.serving.tracing import COMPILES, NULL_TRACER, Tracer
 
 # never generated by workloads (kv_synth keeps keys < 0xFFFFFFF0) and never
 # inserted: probes/deletes padded with it report found=False and write
@@ -100,6 +100,14 @@ OP_KINDS = ("read", "update", "insert", "delete", "scan", "rmw")
 # ``route_cap_totals``; the log keeps the most recent launches for stats()
 # and the bench's fill-ratio telemetry without growing with run length)
 ROUTE_CAP_LOG_MAX = 1024
+
+# slow-tick record (``stats()["slow_ticks"]``): a tick longer than
+# SLOW_TICK_FACTOR x the running median tick is kept, with its phases, in a
+# ring of SLOW_TICK_LOG_MAX; the median is re-read from the collector's
+# tick histogram every SLOW_TICK_REFRESH ticks
+SLOW_TICK_FACTOR = 4.0
+SLOW_TICK_REFRESH = 64
+SLOW_TICK_LOG_MAX = 16
 
 
 @dataclass
@@ -562,7 +570,8 @@ class _TickRecord:
 
 class _PhaseTimer:
     """Context manager pairing one tracer span with one MetricsCollector
-    ``record_phase`` sample (engine._phase_span)."""
+    ``record_phase`` sample (engine._phase_span), and adding the duration
+    to the tick's phase totals for the slow-tick record."""
 
     __slots__ = ("eng", "name", "lane", "args", "tok", "t0")
 
@@ -577,9 +586,12 @@ class _PhaseTimer:
         return self
 
     def __exit__(self, *exc):
-        self.eng.metrics.record_phase(self.name,
-                                      time.perf_counter() - self.t0)
-        self.eng.tracer.end(self.tok)
+        dt = time.perf_counter() - self.t0
+        eng = self.eng
+        eng.metrics.record_phase(self.name, dt)
+        phases = eng._tick_phases
+        phases[self.name] = phases.get(self.name, 0.0) + dt
+        eng.tracer.end(self.tok)
         return False
 
 
@@ -629,6 +641,8 @@ class ServingEngine:
             self.tracer = trace
         else:
             self.tracer = Tracer() if trace else NULL_TRACER
+        COMPILES.watch(self.tracer)
+        self._compiles0 = COMPILES.snapshot()
         self._profile_window = None    # (start_tick, stop_tick, logdir)
         self._profiling = False
         # a profiler start/stop that raised, as its repr: the run goes on
@@ -707,6 +721,11 @@ class ServingEngine:
         # exact schedule against the DictModel (tests/model.py)
         self.record_schedule = record_schedule
         self.schedule: list = []
+        # always-on slow-tick record: seconds per phase of the current tick
+        # (_PhaseTimer adds to it), the running median tick, and the ring
+        self._tick_phases: dict = {}
+        self._tick_p50 = 0.0
+        self.slow_ticks: deque = deque(maxlen=SLOW_TICK_LOG_MAX)
 
     # -- back-compat views -------------------------------------------------
     @property
@@ -751,6 +770,13 @@ class ServingEngine:
         Rejection happens when the global queue is full OR the tenant's own
         pending quota is exhausted.  Raises ValueError on keys colliding
         with the reserved pad/sentinel range."""
+        tok = self.tracer.begin("submit")
+        try:
+            return self._submit(req)
+        finally:
+            self.tracer.end(tok)
+
+    def _submit(self, req: Request) -> str:
         self._validate_ops(req)
         req.rid, self._rid = self._rid, self._rid + 1
         req.submit_time = time.perf_counter()
@@ -931,6 +957,10 @@ class ServingEngine:
         nested in the stalling tick.  The span is *begun* here and *ended*
         by ``_drain_one`` (depth=1 drains within this call)."""
         t0 = time.perf_counter()
+        tick = self.ticks
+        compiles0, traces0 = COMPILES.counts["compiles"], \
+            COMPILES.counts["traces"]
+        self._tick_phases.clear()
         tr = self.tracer
         lane = self.ticks % self.pipeline_depth
         self._cur_lane = lane
@@ -968,8 +998,10 @@ class ServingEngine:
             self._drain_one()
 
         # completion + slot recycling (cursor-driven: a request can complete
-        # while its last results are still in flight)
+        # while its last results are still in flight); the completed
+        # requests' latencies are recorded in one block after the sweep
         with self._phase_span("admit", lane):
+            done = []
             for slot, req in self.pool.active():
                 if req.done():
                     self.pool.release(slot)
@@ -986,32 +1018,54 @@ class ServingEngine:
                     if tr.enabled:
                         tr.async_end("service", req.rid)
                         tr.async_end("request", req.rid, status="ok")
-                    self.metrics.record_request(
-                        self.ticks - req.admit_tick + 1,
-                        now - req.submit_time,
-                        queue_secs=queue_s, service_secs=service_s)
+                    done.append((self.ticks - req.admit_tick + 1,
+                                 now - req.submit_time, queue_s, service_s))
+            if done:
+                with self._phase_span("telemetry", lane):
+                    for ticks, secs, queue_s, service_s in done:
+                        self.metrics.record_request(
+                            ticks, secs, queue_secs=queue_s,
+                            service_secs=service_s)
             self._refill()
 
         self.ticks += 1
-        self.metrics.record_tick(n_ops, self.pool.occupancy(),
-                                 time.perf_counter() - t0)
-        if tr.enabled:
-            tr.counter("occupancy", self.pool.occupancy())
-            tr.counter("tick_ops", n_ops)
-        # throttled telemetry: the "sample" span token is simply dropped on
-        # un-sampled ticks (begin() allocates no ring entry until end())
-        sample_tok = tr.begin("sample", lane)
-        if self.metrics.sample_chains(self.backend.shard_list) \
-                and self._last_probe_keys is not None:
-            rows = self._rows_activated(self._last_probe_keys)
-            self.metrics.record_rows_activated(rows)
-            tr.counter("rows_activated", rows)
-            tr.end(sample_tok)
+        with self._phase_span("telemetry", lane):
+            occupancy = self.pool.occupancy()
+            self.metrics.record_tick(n_ops, occupancy,
+                                     time.perf_counter() - t0)
+            if tr.enabled:
+                tr.counter("occupancy", occupancy)
+                tr.counter("tick_ops", n_ops)
+        # throttled telemetry: a span only on the ticks that sample
+        if self.metrics.chain_sample_due():
+            with tr.span("sample", lane):
+                self.metrics.force_chain_sample(self.backend.shard_list)
+                if self._last_probe_keys is not None:
+                    rows = self._rows_activated(self._last_probe_keys)
+                    self.metrics.record_rows_activated(rows)
+                    tr.counter("rows_activated", rows)
         if self.ticks % self.compact_every == 0:
             self.maybe_compact()
         if self._profiling and self.ticks >= self._profile_window[1]:
             self._stop_profiler()
+        self._note_slow_tick(tick, time.perf_counter() - t0,
+                             COMPILES.counts["compiles"] - compiles0,
+                             COMPILES.counts["traces"] - traces0)
         return n_ops
+
+    def _note_slow_tick(self, tick: int, secs: float, compiles: int,
+                        traces: int):
+        """Keep a tick that took over SLOW_TICK_FACTOR x the running median
+        in ``slow_ticks``, with its phases and the compiles and jaxpr
+        traces it ran; refresh the median every SLOW_TICK_REFRESH ticks."""
+        if secs > SLOW_TICK_FACTOR * self._tick_p50 > 0.0:
+            self.slow_ticks.append({
+                "tick": tick, "ms": secs * 1e3,
+                "phases_ms": {k: v * 1e3
+                              for k, v in self._tick_phases.items()},
+                "compiles": compiles, "traces": traces})
+        if self.ticks % SLOW_TICK_REFRESH == 0:
+            self._tick_p50 = self.metrics.tick_secs_h.percentile(50)
 
     def _gather(self):
         """One op per active slot, expanded into three global phase buffers
@@ -1102,7 +1156,8 @@ class ServingEngine:
             else:
                 raise ValueError(f"unknown op kind {kind!r}")
         if hot:
-            self.metrics.record_hot_keys(hot)
+            with self._phase_span("telemetry", self._cur_lane):
+                self.metrics.record_hot_keys(hot)
         return probes, deletes, inserts, claims, conflict, n_ops
 
     def _issue(self, probes, deletes, inserts, claims) -> _TickRecord:
@@ -1194,15 +1249,18 @@ class ServingEngine:
 
     def _drain_phases(self, rec: _TickRecord):
         for ph in rec.phases:
+            # the host blocks on the phase's device results here
+            with self._phase_span("wait", rec.lane, phase=ph.kind):
+                out = ph.finalize()
             if ph.kind == "probe":
-                vals, found = ph.finalize()
+                vals, found = out
                 hits = 0
                 for i, (_, fwb) in enumerate(ph.entries):
                     fwb((vals[i], found[i]))
                     hits += int(found[i])
                 self.metrics.record_ops("read", len(ph.entries), hits)
             elif ph.kind == "delete":
-                found, tombs = ph.finalize()
+                found, tombs = out
                 for i, (_, fwb) in enumerate(ph.entries):
                     fwb(bool(found[i]))
                 for s, c in tombs.items():
@@ -1211,7 +1269,7 @@ class ServingEngine:
                         self._tombstones[s] += c
                 self.metrics.record_ops("delete", len(ph.entries))
             else:
-                ok = np.array(ph.finalize())     # writable copy (grow repair)
+                ok = np.array(out)               # writable copy (grow repair)
                 failed = np.nonzero(~ok)[0]
                 if failed.size and self.backend.auto_grow:
                     # extendible repair is a per-shard LOCAL mutation ordered
@@ -1404,6 +1462,9 @@ class ServingEngine:
             "trace": {"enabled": self.tracer.enabled,
                       "recorded": self.tracer._recorded,
                       "dropped": self.tracer.dropped},
+            "compiles": {k: v - self._compiles0[k]
+                         for k, v in COMPILES.counts.items()},
+            "slow_ticks": list(self.slow_ticks),
             "chain_depth": {
                 "p50": self.metrics.chain_samples[-1]["chain_p50"]
                 if self.metrics.chain_samples else 0.0,

@@ -188,7 +188,8 @@ class MetricsCollector:
     * ``record_phase(name, seconds)`` — per-phase latency (gather / route /
       fused_tick / writeback / ... — fed from the engine's tracer spans);
     * ``record_hot_keys(keys)`` — folded keys into the top-K sketch;
-    * ``sample_chains(hm)`` — chain-length telemetry from a HashMem.
+    * ``chain_sample_due()`` / ``force_chain_sample(hm)`` — throttled
+      chain-length telemetry from a HashMem.
     """
 
     def __init__(self, chain_sample_every: int = 32, subbuckets: int = 64,
@@ -258,20 +259,20 @@ class MetricsCollector:
         for k in keys:
             self.hot.offer(int(k))
 
-    def sample_chains(self, hms) -> bool:
-        """Throttled chain-length sample over one HashMem, a list of shards
-        (aggregated, so a single hot shard is visible in max_chain), or a
-        zero-arg callable producing either — the mesh-backed engine passes a
-        callable so shard views are only materialized on sampled ticks;
-        returns True when it sampled."""
+    def chain_sample_due(self) -> bool:
+        """Count one tick; True on every ``chain_sample_every``-th, when the
+        caller takes a :meth:`force_chain_sample`."""
         self._ticks_since_chain_sample += 1
         if self._ticks_since_chain_sample < self.chain_sample_every:
             return False
         self._ticks_since_chain_sample = 0
-        self.force_chain_sample(hms)
         return True
 
     def force_chain_sample(self, hms):
+        """Chain-length sample over one HashMem, a list of shards
+        (aggregated, so a single hot shard is visible in max_chain), or a
+        zero-arg callable producing either — the mesh-backed engine passes a
+        callable so shard views are only materialized on sampled ticks."""
         from repro.core import hashmap
         if callable(hms):
             hms = hms()
@@ -292,7 +293,7 @@ class MetricsCollector:
     def record_rows_activated(self, mean_rows: float):
         """Per-sample mean DRAM-row activations per probe, from
         ``hashmap.rows_activated_per_probe`` on a sampled tick's probe keys
-        (the engine throttles this alongside ``sample_chains``)."""
+        (the engine throttles this alongside the chain sample)."""
         self.rows_h.record(finite(mean_rows))
 
     # -- reduction ---------------------------------------------------------

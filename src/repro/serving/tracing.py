@@ -1,17 +1,23 @@
 """Tick-level tracing for the serving engine: structured spans on a bounded
-ring buffer, exported as Chrome/Perfetto trace-event JSON.
+ring buffer, exported as Chrome/Perfetto trace-event JSON, and mirrored onto
+the ``jax.profiler`` timeline.
 
-The :class:`Tracer` is zero-dependency and host-side: every record is a
-plain tuple appended to a ``collections.deque(maxlen=capacity)``, so memory
-is O(1) in run length and a dropped-oldest counter keeps the loss visible.
-Four record families cover the engine's timeline:
+The :class:`Tracer` is host-side: every record is a plain tuple appended to
+a ``collections.deque(maxlen=capacity)``, so memory is O(1) in run length
+and a dropped-oldest counter keeps the loss visible.  Four record families
+cover the engine's timeline:
 
   * **duration spans** — ``with tracer.span("gather", tid=lane): ...`` or
     the split form ``tok = tracer.begin("tick"); ...; tracer.end(tok)`` for
     spans that cross function boundaries (a pipelined tick is *begun* at
     issue and *ended* at drain, possibly several engine ticks later).
     ``tid`` is the track: the engine maps pipeline lanes to tracks so
-    depth>=2 tick spans render side by side and a stall shows as a gap;
+    depth>=2 tick spans render side by side and a stall shows as a gap.
+    While the tracer is enabled and a profiler session runs, each such
+    span also opens and closes a ``jax.profiler.TraceAnnotation`` named
+    ``hashmem/<name>``, so the session holds the engine's phases on the
+    device trace's own clock.  ``complete()`` records a span after the
+    fact (a compile that JAX reports once it has finished): ring only;
   * **counters** — ``tracer.counter("occupancy", v)``: per-tick counter
     tracks (occupancy, route-cap fill, rows activated, routed all_to_all
     element volume);
@@ -22,6 +28,8 @@ Four record families cover the engine's timeline:
     ``service``) keyed by request id, so overlapping requests don't fight
     over one track.
 
+Counters, instants and async slices are ring-only.
+
 ``export(path)`` (or ``to_events()``) emits the Chrome trace-event JSON
 array format: span records become balanced ``B``/``E`` pairs replayed
 through a per-track nesting sweep (timestamps monotonic per track, children
@@ -31,17 +39,25 @@ request slices ``b``/``e`` async pairs — openable directly in Perfetto
 ``tools/trace_report.py``.
 
 A disabled tracer (``Tracer(enabled=False)``, the engine's default via
-``NULL_TRACER``) keeps every record method a single attribute check, so the
-untraced hot path pays one branch per call site; ``benchmarks/
-serving_bench.py`` measures the *enabled* cost as the ``trace_overhead``
-row, gated <=1.10x by ``tools/bench_check.py``.
+``NULL_TRACER``) keeps every record method a single attribute check and
+makes no annotation, so the untraced hot path pays one branch per call
+site; ``benchmarks/serving_bench.py`` measures the *enabled* cost as the
+``trace_overhead`` row, gated <=1.10x by ``tools/bench_check.py``.
+
+:data:`COMPILES` counts XLA compiles and jaxpr traces for the whole process
+through one ``jax.monitoring`` listener, always on; while a watched tracer
+is enabled, each compile also lands in its ring as a ``compile`` span.
 """
 from __future__ import annotations
 
 import json
 import time
+import weakref
 from collections import deque
 from contextlib import contextmanager
+
+from jax import monitoring
+from jax.profiler import TraceAnnotation
 
 # the documented span vocabulary (tools/trace_report.py groups by these;
 # make trace-smoke asserts the core ones appear in a real mesh run)
@@ -59,14 +75,19 @@ SPAN_NAMES = (
     "split",           # same repair point, resize="extendible": per-group
                        # split/doubling — inline, NO pipeline flush
     "compact", "preload",
+    "submit",          # ServingEngine.submit: validation, quota, placement
+    "wait",            # drain: the host blocked on one phase's device results
+    "telemetry",       # always-on MetricsCollector work (hot keys, request
+                       # and tick records)
+    "compile",         # an XLA compile or jaxpr trace (own track, ring only)
 )
-INSTANT_NAMES = ("kill", "write_fence", "deferred_write", "profiler_start",
-                 "profiler_stop")
-COUNTER_NAMES = ("occupancy", "tick_ops", "route_cap_fill", "routed_elems",
-                 "rows_activated")
-REQUEST_SLICES = ("request", "queue", "service")
+
+# profiler-side name of a duration span: ``hashmem/<span name>``, clear of
+# the names other code annotates the same timeline with
+ANNOTATION_PREFIX = "hashmem/"
 
 _PID = 1                       # single-process engine: one trace pid
+COMPILE_TID = 99               # the track of ``compile`` spans
 
 # record kinds in the ring (field layout per kind)
 _SPAN, _COUNTER, _INSTANT, _ABEGIN, _AEND = 0, 1, 2, 3, 4
@@ -105,19 +126,39 @@ class Tracer:
 
     # -- spans -------------------------------------------------------------
     def begin(self, name: str, tid: int = 0, **args):
-        """Open a span; returns a token for :meth:`end`.  Use for spans
+        """Open a span, and its profiler annotation while a profiler
+        session runs; returns a token for :meth:`end`.  Use for spans
         that outlive the current scope (the engine's tick span stays open
         across pipelined ticks until drain)."""
         if not self.enabled:
             return None
-        return (name, tid, self.now_us(), args)
+        ann = None
+        if TraceAnnotation.is_enabled():          # a profiler session runs
+            ann = TraceAnnotation(ANNOTATION_PREFIX + name)
+            ann.__enter__()
+        return (name, tid, self.now_us(), args, ann)
 
     def end(self, token):
-        """Close a span opened by :meth:`begin` (None tokens no-op)."""
-        if token is None or not self.enabled:
+        """Close a span opened by :meth:`begin` (None tokens no-op).  The
+        annotation closes even if the tracer was disabled in between; the
+        ring records the span only while enabled."""
+        if token is None:
             return
-        name, tid, ts, args = token
-        self._emit((_SPAN, name, tid, ts, self.now_us() - ts, args))
+        name, tid, ts, args, ann = token
+        dur = self.now_us() - ts
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if self.enabled:
+            self._emit((_SPAN, name, tid, ts, dur, args))
+
+    def complete(self, name: str, ts: float, dur: float, tid: int = 0,
+                 **args):
+        """Record a finished span of ``dur`` us from ``ts`` (the
+        :meth:`now_us` timebase) in the ring alone: it is over, so no
+        annotation can be opened for it."""
+        if not self.enabled:
+            return
+        self._emit((_SPAN, name, tid, ts, dur, args))
 
     @contextmanager
     def span(self, name: str, tid: int = 0, **args):
@@ -198,8 +239,9 @@ class Tracer:
         meta = [{"name": "process_name", "ph": "M", "pid": _PID,
                  "args": {"name": "ServingEngine"}}]
         for tid in sorted(spans):
+            track = "compile" if tid == COMPILE_TID else f"lane {tid}"
             meta.append({"name": "thread_name", "ph": "M", "pid": _PID,
-                         "tid": tid, "args": {"name": f"lane {tid}"}})
+                         "tid": tid, "args": {"name": track}})
         return meta + events
 
     @staticmethod
@@ -239,3 +281,49 @@ class Tracer:
 #: shared disabled tracer — the engine's default, so call sites never
 #: branch on None (every record method is one ``self.enabled`` check)
 NULL_TRACER = Tracer(capacity=1, enabled=False)
+
+
+class CompileCounter:
+    """Counts of XLA backend compiles and jaxpr traces, and their seconds,
+    from ``jax.monitoring``'s duration events.  One listener serves the
+    process (the events are process-wide); it is registered by the first
+    :meth:`watch`.  A compile that hits JAX's persistent cache still counts:
+    the event times the lookup too.  Each watched tracer that is enabled
+    when a compile ends gets a ``compile`` span on its own track."""
+
+    EVENTS = {"/jax/core/compile/backend_compile_duration": "compile",
+              "/jax/core/compile/jaxpr_trace_duration": "trace"}
+
+    def __init__(self):
+        self.counts = {"compiles": 0, "compile_s": 0.0,
+                       "traces": 0, "trace_s": 0.0}
+        self._tracers: weakref.WeakSet = weakref.WeakSet()
+        self._listening = False
+
+    def watch(self, tracer: Tracer):
+        """Start counting, and give ``tracer`` a span for each compile."""
+        if not self._listening:
+            monitoring.register_event_duration_secs_listener(self._on_event)
+            self._listening = True
+        if tracer is not NULL_TRACER:
+            self._tracers.add(tracer)
+
+    def _on_event(self, event: str, seconds: float, **kwargs):
+        kind = self.EVENTS.get(event)
+        if kind is None:
+            return
+        self.counts[kind + "s"] += 1
+        self.counts[kind + "_s"] += seconds
+        for tr in list(self._tracers):
+            if tr.enabled:
+                dur = seconds * 1e6
+                tr.complete("compile", tr.now_us() - dur, dur, COMPILE_TID,
+                            kind=kind, fn=kwargs.get("fun_name", ""))
+
+    def snapshot(self) -> dict:
+        return dict(self.counts)
+
+
+#: the process's compile counter (``ServingEngine.stats()["compiles"]``
+#: reports its counts since the engine was built)
+COMPILES = CompileCounter()

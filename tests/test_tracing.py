@@ -375,5 +375,143 @@ def test_tenant_queue_service_split_accumulates():
     assert snap["service_ms"]["p50"] > 0.0
 
 
+# ---------------------------------------------------------------------------
+# profiler annotations, compile counter, slow-tick record
+# ---------------------------------------------------------------------------
+
+def _count_annotations(monkeypatch, session=True):
+    """Stand in for ``jax.profiler.TraceAnnotation``, with a profiler
+    session running or not: the log counts what the tracer opens and
+    closes, by name."""
+    from repro.serving import tracing
+    log: list = []
+
+    class CountingAnnotation:
+        def __init__(self, name):
+            self.name = name
+            log.append(("new", name))
+
+        @staticmethod
+        def is_enabled():
+            return session
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", CountingAnnotation)
+    return log
+
+
+def test_disabled_tracer_makes_no_annotation(monkeypatch):
+    log = _count_annotations(monkeypatch)
+    run_engine(depth=2, trace=False)
+    tr = Tracer(enabled=False)
+    with tr.span("tick"):
+        tr.end(tr.begin("gather"))
+    assert log == []
+
+
+def test_enabled_tracer_outside_a_profiler_session_makes_no_annotation(
+        monkeypatch):
+    log = _count_annotations(monkeypatch, session=False)
+    eng, _ = run_engine(depth=2)
+    assert any(s[0] == "submit" for s in
+               validate_events(eng.tracer.to_events()))
+    assert log == []
+
+
+def test_enabled_tracer_annotates_each_span_once(monkeypatch):
+    log = _count_annotations(monkeypatch)
+    eng, _ = run_engine(depth=2)
+    ring = defaultdict(int)
+    for name, *_ in validate_events(eng.tracer.to_events()):
+        if name != "compile":          # recorded after the fact: ring only
+            ring["hashmem/" + name] += 1
+    for what in ("new", "enter", "exit"):
+        got = defaultdict(int)
+        for w, name in log:
+            if w == what:
+                got[name] += 1
+        assert got == ring, what
+    assert {"hashmem/submit", "hashmem/wait",
+            "hashmem/telemetry"} <= set(ring)
+
+
+def test_sample_span_only_on_sampled_ticks(monkeypatch):
+    from repro.serving.metrics import MetricsCollector
+    log = _count_annotations(monkeypatch)
+    eng = ServingEngine(num_shards=1, max_slots=4, trace=True,
+                        metrics=MetricsCollector(chain_sample_every=3))
+    eng.preload(np.arange(8, dtype=np.uint32), np.arange(8, dtype=np.uint32))
+    eng.submit_all([Request(ops=[("read", k % 8)] * 4) for k in range(8)])
+    while eng.ticks < 9:
+        eng.tick()
+    spans = validate_events(eng.tracer.to_events())
+    assert sum(s[0] == "sample" for s in spans) == 3
+    assert log.count(("enter", "hashmem/sample")) == \
+        log.count(("exit", "hashmem/sample")) == 3
+
+
+def test_compile_counter_rises_on_new_shape_not_on_cache_hit():
+    from repro.serving import tracing
+    from repro.serving.metrics import MetricsCollector
+    eng = ServingEngine(num_shards=1, max_slots=4, trace=True,
+                        metrics=MetricsCollector(chain_sample_every=1 << 30))
+    eng.preload(np.arange(64, dtype=np.uint32),
+                np.arange(64, dtype=np.uint32))
+
+    def serve(ops):
+        eng.submit_all([Request(ops=list(ops)) for _ in range(4)])
+        eng.run()
+        return eng.stats()["compiles"]
+
+    serve([("read", 1), ("update", 2, 3)])
+    warm = serve([("read", 1), ("update", 2, 3)])
+    assert serve([("read", 5), ("update", 6, 7)]) == warm   # cache hits
+    n_compile = sum(s[0] == "compile" for s in
+                    validate_events(eng.tracer.to_events()))
+    # a 40-key scan makes a probe batch of 64, a shape not yet compiled
+    grown = serve([("scan", 0, 40)])
+    assert grown["compiles"] > warm["compiles"]
+    assert grown["compile_s"] > warm["compile_s"]
+    spans = validate_events(eng.tracer.to_events())
+    assert sum(s[0] == "compile" for s in spans) > n_compile
+    assert all(s[1] == tracing.COMPILE_TID for s in spans
+               if s[0] == "compile")
+
+
+def test_slow_tick_lands_in_the_record_with_its_phases(monkeypatch):
+    import time
+    from repro.serving import engine as engine_mod
+    eng = ServingEngine(num_shards=1, max_slots=4)
+    eng.preload(np.arange(8, dtype=np.uint32), np.arange(8, dtype=np.uint32))
+    eng.submit_all([Request(ops=[("read", k % 8)] * 200) for k in range(4)])
+    while eng.ticks < engine_mod.SLOW_TICK_REFRESH:
+        eng.tick()
+    median_s = eng.metrics.tick_secs_h.percentile(50)
+    gather = eng._gather
+
+    def slow_gather():
+        time.sleep(max(0.05, 8 * median_s))
+        return gather()
+    monkeypatch.setattr(eng, "_gather", slow_gather)
+    slowed = eng.ticks
+    eng.tick()
+    monkeypatch.setattr(eng, "_gather", gather)
+    eng.tick()
+    rec = [r for r in eng.stats()["slow_ticks"] if r["tick"] == slowed]
+    assert len(rec) == 1
+    phases = rec[0]["phases_ms"]
+    assert max(phases, key=phases.get) == "gather"
+    assert {"gather", "probe", "wait", "writeback", "admit",
+            "telemetry"} <= set(phases)
+    assert rec[0]["ms"] >= phases["gather"] >= 50.0
+    assert rec[0]["compiles"] == 0
+    assert len(eng.slow_ticks) <= engine_mod.SLOW_TICK_LOG_MAX
+
+
 if __name__ == "__main__":
     sys.exit(pytest.main([__file__, "-v"]))

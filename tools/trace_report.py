@@ -32,9 +32,10 @@ import sys
 from collections import defaultdict
 
 # engine span vocabulary (tracing.SPAN_NAMES), used for breakdown ordering
-PHASE_ORDER = ("gather", "route", "probe", "delete", "insert", "fused_tick",
-               "writeback", "pipeline_stall", "admit", "sample", "grow",
-               "split", "compact", "preload")
+PHASE_ORDER = ("submit", "gather", "route", "probe", "delete", "insert",
+               "fused_tick", "wait", "writeback", "pipeline_stall", "admit",
+               "telemetry", "sample", "grow", "split", "compact", "preload",
+               "compile")
 
 
 def load_events(path: str) -> tuple:
