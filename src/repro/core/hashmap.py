@@ -464,7 +464,7 @@ def rows_activated_per_probe(hm: HashMem, queries: jax.Array,
     if use_fingerprints and hm.store.fprints is not None:
         pages = _fp_filter(hm.store, q, pages)
     valid = pages >= 0
-    rows = hm.key_pages[jnp.maximum(pages, 0)]                        # (Q,C,S)
+    rows = hm.store.key_rows(pages)                                   # (Q,C,S)
     pmatch = jnp.any(rows == q[:, None, None], axis=-1) & valid
     anym = jnp.any(pmatch, axis=1)
     first = jnp.argmax(pmatch, axis=1)
@@ -741,7 +741,7 @@ def delete_with_buckets(hm: HashMem, keys: jax.Array, b: jax.Array):
     slots = cfg.slots_per_page
     q = keys.astype(U32)
     pages = resolve_pages_by_bucket(hm, b.astype(I32))                     # (Q, C)
-    rows = hm.key_pages[jnp.maximum(pages, 0)]                             # (Q, C, S)
+    rows = hm.store.key_rows(pages)                                        # (Q, C, S)
     match = (rows == q[:, None, None]) & (pages >= 0)[:, :, None]
     qn, C = pages.shape
     flat = match.reshape(qn, C * slots)
@@ -782,11 +782,11 @@ def _delete_displaced(hm: HashMem, keys: jax.Array, b1: jax.Array):
     S = cfg.slots_per_page
     q = keys.astype(U32)
     pages = resolve_pages_displaced(hm, q, b1.astype(I32))                 # (Q, C)
-    rows = hm.key_pages[jnp.maximum(pages, 0)]
+    st = hm.store
+    rows = st.key_rows(pages)
     match = (rows == q[:, None, None]) & (pages >= 0)[:, :, None]
     qn, C = pages.shape
     flat = match.reshape(qn, C * S)
-    st = hm.store
     if st.stash is not None:
         flat = jnp.concatenate([flat, st.stash[None, :, 0] == q[:, None]],
                                axis=1)

@@ -103,6 +103,12 @@ class PageStore:
     def val_pages(self) -> jax.Array:
         return self.pool[..., VAL_LANE]
 
+    def key_rows(self, pages: jax.Array) -> jax.Array:
+        """Key lane of just the rows ``pages`` names (any shape, -1 read as
+        page 0): one gather on the pool, never a pass over the whole key
+        plane that ``key_pages`` would materialise."""
+        return self.pool[jnp.maximum(pages, 0), :, KEY_LANE]
+
     @property
     def num_pages(self) -> int:
         return self.pool.shape[0]
@@ -134,12 +140,16 @@ class PageStore:
 
     def write_keys(self, pages, slots_idx, keys,
                    plane_pages=None) -> "PageStore":
-        """Key-lane-only scatter (tombstone writes): the value lane of the
-        row is left untouched.  ``plane_pages`` optionally overrides the
-        page ids used for the bit-plane update (delete dedups duplicate
-        targets there)."""
-        pool = self.pool.at[pages, slots_idx, KEY_LANE].set(
-            keys.astype(U32), mode="drop")
+        """Key-lane-only write (tombstone writes): the value lane of the
+        row is left as it was.  Each slot's value is read back and the
+        slot is written whole, so the scatter keeps the pool's own layout
+        (a scatter into the key lane alone has XLA's TPU backend relayout
+        the whole pool, and back).  ``plane_pages`` optionally overrides
+        the page ids used for the bit-plane update (delete dedups
+        duplicate targets there)."""
+        vals = self.pool[pages, slots_idx, VAL_LANE]  # out of range: unused
+        kv = jnp.stack([keys.astype(U32), vals], axis=-1)
+        pool = self.pool.at[pages, slots_idx].set(kv, mode="drop")
         pp = pages if plane_pages is None else plane_pages
         planes = self.planes
         if planes is not None:

@@ -72,6 +72,7 @@ gather time and once per probed key at writeback — ops routed to any shard
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -229,9 +230,56 @@ _jit_cache: dict = {}
 def _jitted(name: str):
     if not _jit_cache:
         _jit_cache["probe"] = jax.jit(hashmap.probe)
-        _jit_cache["delete"] = jax.jit(hashmap.delete)
+        _jit_cache["delete"] = DeleteInPlace()
         _jit_cache["insert"] = jax.jit(hashmap.insert)
     return _jit_cache[name]
+
+
+def _with_pool(hm, pool):
+    """``hm`` with its page pool replaced by ``pool`` (None: the rest of
+    the table, which the delete program takes apart from the pool)."""
+    return dataclasses.replace(
+        hm, store=dataclasses.replace(hm.store, pool=pool))
+
+
+def _own_pool(hm):
+    """``hm`` over a fresh copy of its pool, for the delete program to
+    consume in place of the pool a caller still holds."""
+    return _with_pool(hm, jnp.copy(hm.store.pool))
+
+
+class DeleteInPlace:
+    """``hashmap.delete`` as ``(hm, keys) -> (hm', found)``, compiled with
+    the table's pool donated: the tombstones are written into the pool it
+    is given, with no pass over the whole pool.  Only the pool is donated,
+    so a table kept from before a call still reads its bucket heads and
+    chain links (``hashmap.chain_lengths``); its pool is gone.
+
+    ``not_aliased`` counts the compiled programs (one per shape) whose
+    output pool does not reuse the donated one, where XLA copies after all.
+    """
+
+    def __init__(self):
+        def delete(pool, rest, keys):  # so named: ``jit_delete`` in traces
+            hm, found = hashmap.delete(_with_pool(rest, pool), keys)
+            return hm.store.pool, _with_pool(hm, None), found
+        self._jit = jax.jit(delete, donate_argnums=0)
+        self._shapes: set = set()
+        self.not_aliased = 0
+
+    def __call__(self, hm, keys):
+        pool, rest, found = self._jit(hm.store.pool, _with_pool(hm, None),
+                                      keys)
+        shape = (hm.config, keys.shape, keys.dtype)
+        if shape not in self._shapes:      # compiled by the call above
+            self._shapes.add(shape)
+            mem = self._jit.lower(pool, rest, keys).compile() \
+                .memory_analysis()
+            self.not_aliased += mem.alias_size_in_bytes < pool.nbytes
+        return _with_pool(rest, pool), found
+
+    def lower(self, hm, keys):
+        return self._jit.lower(hm.store.pool, _with_pool(hm, None), keys)
 
 
 # ---------------------------------------------------------------------------
@@ -245,10 +293,15 @@ class _HostShards:
 
     is_mesh = False
 
-    def __init__(self, eng: "ServingEngine", shards: list):
+    def __init__(self, eng: "ServingEngine", shards: list,
+                 borrowed: bool = False):
         self.eng = eng
         self.shards = shards
         self.num_shards = len(shards)
+        # tables a caller handed in (``borrowed``): the delete program
+        # consumes the pool it is given, so the first delete on each shard
+        # takes a copy of the pool first and the caller's table stays whole
+        self._borrowed = [borrowed] * self.num_shards
 
     @property
     def auto_grow(self) -> bool:
@@ -279,11 +332,16 @@ class _HostShards:
         return finalize
 
     def delete(self, keys: np.ndarray, pad: bool = True):
+        """One jitted delete per touched shard; it consumes the shard's
+        pool (``DeleteInPlace``)."""
         owners = self.owners(keys)
         parts = []
         for s in np.unique(owners):
             idx = np.nonzero(owners == s)[0]
             q = self.eng._padded(keys[idx], PAD_KEY, pad)
+            if self._borrowed[s]:
+                self.shards[s] = _own_pool(self.shards[s])
+                self._borrowed[s] = False
             self.shards[s], f = self.eng._jit_delete(self.shards[s],
                                                      jnp.asarray(q))
             self.eng._record_call("delete")
@@ -670,10 +728,11 @@ class ServingEngine:
         if mesh is not None:
             base = tables[0].config if tables else cfg
             self.backend = _MeshShards(self, mesh, mesh_axis, base, tables)
+        elif tables is not None:
+            self.backend = _HostShards(self, list(tables), borrowed=True)
         else:
-            shards = list(tables) if tables is not None else \
-                [hashmap.create(cfg) for _ in range(num_shards)]
-            self.backend = _HostShards(self, shards)
+            self.backend = _HostShards(
+                self, [hashmap.create(cfg) for _ in range(num_shards)])
         self.num_shards = self.backend.num_shards
         # mesh phases must split evenly across devices
         self._pad_multiple = self.num_shards if mesh is not None else 1
@@ -1036,6 +1095,8 @@ class ServingEngine:
             if tr.enabled:
                 tr.counter("occupancy", occupancy)
                 tr.counter("tick_ops", n_ops)
+                for k, v in self._delete_in_place().items():
+                    tr.counter("delete_in_place." + k, v)
         # throttled telemetry: a span only on the ticks that sample
         if self.metrics.chain_sample_due():
             with tr.span("sample", lane):
@@ -1439,6 +1500,14 @@ class ServingEngine:
                 self._tombstones[s] = 0
                 self._shard_epochs[s] += 1
 
+    def _delete_in_place(self) -> dict:
+        """Delete calls given a donated pool (every host-shard delete; the
+        mesh path donates nothing), and the process's compiled delete
+        programs that copied the pool all the same."""
+        return {"calls": 0 if self.backend.is_mesh
+                else self.batch_calls["delete"],
+                "not_aliased": self._jit_delete.not_aliased}
+
     def stats(self) -> dict:
         return {
             "ticks": self.ticks,
@@ -1464,6 +1533,7 @@ class ServingEngine:
                       "dropped": self.tracer.dropped},
             "compiles": {k: v - self._compiles0[k]
                          for k, v in COMPILES.counts.items()},
+            "delete_in_place": self._delete_in_place(),
             "slow_ticks": list(self.slow_ticks),
             "chain_depth": {
                 "p50": self.metrics.chain_samples[-1]["chain_p50"]

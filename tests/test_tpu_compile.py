@@ -112,3 +112,20 @@ def test_fused_mesh_tick_carries_the_kernel(mesh4):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-to-all" in text
+
+
+def test_engine_delete_writes_the_pool_in_place(one_chip):
+    """The serving engine's delete program at the one-chip table's size
+    (2^18 buckets + 2^16 overflow pages of 512 slots, a 512-key batch):
+    the donated pool is the output pool, and the program holds no
+    pool-sized buffer of its own: no copy, no relayout, no key plane."""
+    from repro.serving import engine
+    cfg = HashMemConfig(num_buckets=2**18, slots_per_page=S,
+                        overflow_pages=2**16, max_chain=C, backend="perf")
+    hm = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                      jax.eval_shape(lambda: hashmap.create(cfg)))
+    keys = _sds((512,), jnp.uint32, one_chip)
+    compiled = engine.DeleteInPlace().lower(hm, keys).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == POOL_PAGES * S * 2 * 4
+    assert mem.temp_size_in_bytes < 1 << 26
