@@ -32,17 +32,13 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
 def readings(root: Path, workload: str, seed: int, seconds: float) -> dict:
-    import jax
     from perfbench import harness, reference
     cell = harness.load_cell(root, workload)
     config, traffic = cell.config, cell.traffic
     words = reference.seed_words(seed)
     space = harness.keyspace(config)
-    eng, tenants = harness.make_engine(config, traffic,
-                                       harness.build_table(config, words))
-    loop = harness.ClosedLoop(
-        eng, harness.make_clients(config, traffic, tenants, seed))
-    jax.block_until_ready(eng.shards)
+    eng, clients, _ = harness.set_up(config, traffic, seed, words)
+    loop = harness.ClosedLoop(eng, clients)
     loop.start()
     for _ in range(traffic["warmup_ticks"]):    # as a run warms up, so the
         loop.tick(record=False)                 # window holds as many ops

@@ -6,6 +6,10 @@ Device operations are the events of the ``XLA Ops`` line of each
 ``/device:TPU:<n>`` plane, programs those of its ``XLA Modules`` line.
 Host spans (the harness's ``jax.profiler.TraceAnnotation``) are events of
 the host planes, on the same clock.
+
+A cell's chips are the planes ``/device:TPU:<id>`` of its devices' ids
+(:func:`plane`).  Readers given those ``planes`` count a chip that ran
+nothing as idle; given none, they read the device planes that hold events.
 """
 from __future__ import annotations
 
@@ -48,9 +52,21 @@ def load(logdir: str) -> list:
     return events
 
 
+def plane(device_id: int) -> str:
+    """The trace plane of the device with JAX id ``device_id``."""
+    return f"{DEVICE_PREFIX}{device_id}"
+
+
 def device_planes(events) -> list:
     return sorted({e.plane for e in events
                    if e.plane.startswith(DEVICE_PREFIX)})
+
+
+def on_planes(events, planes: list) -> list:
+    """``events`` without the device events of planes not in ``planes``."""
+    keep = set(planes)
+    return [e for e in events
+            if not e.plane.startswith(DEVICE_PREFIX) or e.plane in keep]
 
 
 def ops(events, plane: str | None = None) -> list:
@@ -58,9 +74,9 @@ def ops(events, plane: str | None = None) -> list:
             and e.line == OPS_LINE and (plane is None or e.plane == plane)]
 
 
-def modules(events) -> list:
+def modules(events, plane: str | None = None) -> list:
     return [e for e in events if e.plane.startswith(DEVICE_PREFIX)
-            and e.line == MODULES_LINE]
+            and e.line == MODULES_LINE and (plane is None or e.plane == plane)]
 
 
 def host_spans(events, name: str) -> list:
@@ -81,10 +97,11 @@ def merged(intervals, lo: float, hi: float) -> list:
     return out
 
 
-def busy_ns(events, lo: float, hi: float) -> float:
-    """Seconds (in ns) in which an operation ran on the device, averaged
-    over the device planes: the union of each plane's op intervals."""
-    planes = device_planes(events)
+def busy_ns(events, lo: float, hi: float, planes: list | None = None) \
+        -> float:
+    """Seconds (in ns) in which an operation ran on a device, averaged
+    over the ``planes``: the union of each plane's op intervals."""
+    planes = device_planes(events) if planes is None else planes
     if not planes:
         return 0.0
     total = sum(sum(e - s for s, e in
@@ -94,13 +111,14 @@ def busy_ns(events, lo: float, hi: float) -> float:
     return total / len(planes)
 
 
-def gaps(events, lo: float, hi: float) -> list:
-    """Idle intervals of the first device plane inside [lo, hi]."""
-    planes = device_planes(events)
+def gaps(events, lo: float, hi: float, planes: list | None = None) -> list:
+    """Intervals inside [lo, hi] in which no operation ran on any of the
+    ``planes``: where every chip of the cell was idle."""
+    planes = device_planes(events) if planes is None else planes
     if not planes:
         return []
-    busy = merged(((o.start_ns, o.end_ns) for o in ops(events, planes[0])),
-                  lo, hi)
+    busy = merged(((o.start_ns, o.end_ns) for p in planes
+                   for o in ops(events, p)), lo, hi)
     out, t = [], lo
     for s, e in busy:
         if s > t:
@@ -121,20 +139,22 @@ def short_name(name: str) -> str:
 
 
 def time_by_name(events, lo: float, hi: float) -> dict:
-    """Device ns per operation, clipped to [lo, hi], each operation named
-    ``<program>/<op>`` by the program event that holds its start."""
-    mods = sorted((m.start_ns, m.end_ns, short_name(m.name))
-                  for m in modules(events))
-    starts = [m[0] for m in mods]
+    """Device ns per operation, clipped to [lo, hi] and summed over the
+    device planes, each operation named ``<program>/<op>`` by the program
+    event of its plane that holds its start."""
     out: dict = {}
-    for e in ops(events):
-        d = min(e.end_ns, hi) - max(e.start_ns, lo)
-        if d <= 0:
-            continue
-        i = bisect.bisect_right(starts, e.start_ns) - 1
-        prog = mods[i][2] if i >= 0 and e.start_ns < mods[i][1] else "?"
-        name = f"{prog}/{short_name(e.name)}"
-        out[name] = out.get(name, 0.0) + d
+    for p in device_planes(events):
+        mods = sorted((m.start_ns, m.end_ns, short_name(m.name))
+                      for m in modules(events, p))
+        starts = [m[0] for m in mods]
+        for e in ops(events, p):
+            d = min(e.end_ns, hi) - max(e.start_ns, lo)
+            if d <= 0:
+                continue
+            i = bisect.bisect_right(starts, e.start_ns) - 1
+            prog = mods[i][2] if i >= 0 and e.start_ns < mods[i][1] else "?"
+            name = f"{prog}/{short_name(e.name)}"
+            out[name] = out.get(name, 0.0) + d
     return out
 
 

@@ -4,7 +4,8 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 * ``<file>`` of the configuration entry: the deployment (table shape,
-  tenants, records, guarantees);
+  tenants, records, guarantees, and ``shards``, the chips its one table is
+  split over: 1 when absent, and then the cell's ``chips``);
 * ``perfbench/traffic/<traffic>.json``: the mix (YCSB workloads per tenant,
   key distribution, clients, requests per client, warm-up ticks);
 * ``perfbench/metrics/<name>.py``: a reader with ``read(run)`` that returns
@@ -25,11 +26,14 @@ from __future__ import annotations
 import dataclasses
 import gc
 import importlib.util
+import inspect
 import json
+import math
 import shutil
 import sys
 import tempfile
 import time
+from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 
 import numpy as np
@@ -43,6 +47,9 @@ CACHE_DIR = ".jax_cache"
 # sampled engine telemetry is off: it runs eager ops at the tick's unpadded
 # probe count, which compile anew inside the window (PERF.md, open questions)
 NO_SAMPLING = 1 << 62
+# record ids a shard build hashes at a time: the buffers of one chunk stay
+# small beside the shard's table
+BUILD_CHUNK = 1 << 24
 
 
 @dataclasses.dataclass
@@ -65,6 +72,11 @@ def load_cell(root: Path, workload: str) -> Cell:
     w = cells[workload]
     entry = next(c for c in bench["configs"] if c["name"] == w["config"])
     config = json.loads((root / entry["file"]).read_text())
+    if w["chips"] != shards(config):
+        raise ValueError(
+            f"cell {workload!r} asks for {w['chips']} chip(s), but its "
+            f"configuration {w['config']!r} splits its table over "
+            f"{shards(config)} shard(s); they must agree")
     traffic = json.loads(
         (root / BENCH_DIR / "traffic" / f"{w['traffic']}.json").read_text())
     if traffic["loop"] != "closed":
@@ -109,9 +121,39 @@ def keyspace(config: dict) -> Keyspace:
                     32 - bits if bits else 32)
 
 
+def shards(config: dict) -> int:
+    """The chips the configuration's one table is split over."""
+    return config.get("shards", 1)
+
+
 def table_config(config: dict):
+    """The table's shape; over several shards, each shard's."""
     from repro.configs.base import HashMemConfig
     return HashMemConfig(**config["table"])
+
+
+def router() -> str:
+    """The router that the engine, built with its default arguments as
+    :func:`make_engine` builds it, sends each key to its shard with; shard
+    builds place keys by it, so set-up and serving cannot disagree."""
+    from repro.serving import ServingEngine
+    return inspect.signature(ServingEngine).parameters["shard_by"].default
+
+
+def serving_mesh(config: dict):
+    """The mesh of one shard per chip that the table is split over; None
+    for a table on one chip."""
+    if shards(config) == 1:
+        return None
+    from repro.launch.mesh import make_serving_mesh
+    return make_serving_mesh(shards(config))
+
+
+def folded_keys(space: Keyspace, i):
+    """The folded key of each record id ``i`` (tenant-major)."""
+    if space.key_bits == 32:
+        return i
+    return ((i // space.records) << space.key_bits) | (i % space.records)
 
 
 def build_table(config: dict, words: tuple):
@@ -125,12 +167,88 @@ def build_table(config: dict, words: tuple):
     cfg = table_config(config)
 
     def make(w):
-        i = jnp.arange(space.size, dtype=jnp.uint32)
-        keys = i if space.key_bits == 32 else \
-            ((i // space.records) << space.key_bits) | (i % space.records)
+        keys = folded_keys(space, jnp.arange(space.size, dtype=jnp.uint32))
         return hashmap.build(cfg, keys, values_of(keys, (w[0], w[1]), jnp))
 
     return jax.jit(make)(jnp.asarray(words, jnp.uint32))
+
+
+def shard_capacity(config: dict) -> int:
+    """Records a shard build makes room for: an even share and eight
+    standard deviations of a binomial share, plus a little."""
+    even = -(-keyspace(config).size // shards(config))
+    return even + 8 * math.isqrt(even) + 64
+
+
+def shard_program(config: dict, n: int):
+    """The jitted build of one of ``n`` shards, from (the seed's two words,
+    the shard's index): it walks the record ids in chunks, keeps the keys
+    that the engine's router (:func:`router`, through
+    ``rlu.owner_and_local_bucket``) gives the shard, and builds them with
+    their values into the local buckets that router gives them.  Returns
+    (the shard's table, its count of records)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import hashmap, rlu
+    from repro.core.hashing import EMPTY_KEY
+    space = keyspace(config)
+    cfg = table_config(config)
+    by, cap = router(), shard_capacity(config)
+    chunk = min(BUILD_CHUNK, space.size)
+    chunks = -(-space.size // chunk)
+    u32 = jnp.uint32
+
+    def make(w):
+        def take(c, carry):
+            out, count = carry
+            i = c.astype(u32) * u32(chunk) + jnp.arange(chunk, dtype=u32)
+            keys = folded_keys(space, i)
+            owner, _ = rlu.owner_and_local_bucket(keys, cfg, n, by)
+            mine = (owner == w[2].astype(jnp.int32)) & (i < u32(space.size))
+            at = count + jnp.cumsum(mine, dtype=jnp.int32) - 1
+            out = out.at[jnp.where(mine, at, cap)].set(keys, mode="drop")
+            return out, count + jnp.sum(mine, dtype=jnp.int32)
+
+        keys, count = jax.lax.fori_loop(
+            0, chunks, take, (jnp.full(cap, EMPTY_KEY, u32), jnp.int32(0)))
+        _, local = rlu.owner_and_local_bucket(keys, cfg, n, by)
+        # the room past the shard's records: EMPTY_KEY in no bucket, which
+        # the bulk loader drops
+        local = jnp.where(jnp.arange(cap) < count, local, cfg.num_buckets)
+        vals = values_of(keys, (w[0], w[1]), jnp)
+        return hashmap.build_with_buckets(cfg, keys, vals, local), count
+
+    return jax.jit(make)
+
+
+def build_shards(config: dict, words: tuple, devices: list) -> tuple:
+    """Shard ``s`` of the table on ``devices[s]``, for every shard, each
+    built by :func:`shard_program` on its own device, so no device holds
+    the whole key set.  The programs are dispatched from one thread per
+    device, so they compile and run side by side; returns (the shard
+    tables, each shard's count of records) as soon as all are
+    dispatched."""
+    import jax
+    import jax.numpy as jnp
+    program = shard_program(config, len(devices))
+
+    def dispatch(s):
+        return program(jax.device_put(
+            jnp.asarray((*words, s), jnp.uint32), devices[s]))
+
+    with ThreadPoolExecutor(len(devices)) as pool:
+        built = list(pool.map(dispatch, range(len(devices))))
+    return [t for t, _ in built], [c for _, c in built]
+
+
+def check_owned(config: dict, owned: list):
+    """Every record in exactly one shard build, and within its room."""
+    got = [int(c) for c in owned]
+    cap = shard_capacity(config)
+    if sum(got) != keyspace(config).size or max(got) > cap:
+        raise RuntimeError(f"shard builds hold {got} records (room {cap} "
+                           f"each), not the {keyspace(config).size} records "
+                           "split over them")
 
 
 class OpLog:
@@ -177,9 +295,11 @@ class OpLog:
         return len(self.sealed) + len(self.open)
 
 
-def make_engine(config: dict, traffic: dict, table, tracer=None):
+def make_engine(config: dict, traffic: dict, table, tracer=None,
+                mesh=None):
     """The engine over ``table``, with the tenants registered in order;
-    returns (engine, [tenant or None per tenant index])."""
+    over a ``mesh``, ``table`` is the list of shard tables in the mesh's
+    device order.  Returns (engine, [tenant or None per tenant index])."""
     from repro.serving import ServingEngine, TenantRegistry
     from repro.serving.metrics import MetricsCollector
     space = keyspace(config)
@@ -193,12 +313,47 @@ def make_engine(config: dict, traffic: dict, table, tracer=None):
             if t.tid != i or reg.fold(t.tid, [0, space.records - 1]).tolist() \
                     != want:
                 raise RuntimeError("tenant folding differs from the build's")
-    eng = ServingEngine(table_config(config), tables=[table],
+    tables = [table] if mesh is None else list(table)
+    eng = ServingEngine(table_config(config), tables=tables, mesh=mesh,
                         max_slots=traffic["clients"], tenants=reg,
                         record_schedule=True, trace=tracer,
                         metrics=MetricsCollector(chain_sample_every=NO_SAMPLING))
     eng.schedule = OpLog()
     return eng, tenants
+
+
+def set_up(config: dict, traffic: dict, seed: int, words: tuple,
+           tracer=None) -> tuple:
+    """The table built from the seed on its chips, the engine over it and
+    the clients' streams, drawn while the build runs.  Returns (engine,
+    clients, the table's devices in shard order) once the table is on
+    them."""
+    import jax
+    mesh = serving_mesh(config)
+    t = time.perf_counter()
+    if mesh is None:
+        devices = jax.devices()[:1]
+        table, owned = build_table(config, words), None
+    else:
+        devices = list(mesh.devices.flat)
+        table, owned = build_shards(config, words, devices)
+    t_build = time.perf_counter() - t
+    eng, tenants = make_engine(config, traffic, table, tracer, mesh)
+    del table
+    clients = make_clients(config, traffic, tenants, seed)
+    t_streams = time.perf_counter() - t
+    if owned is not None:
+        def ready_s(count):
+            count.block_until_ready()
+            return time.perf_counter() - t
+        with ThreadPoolExecutor(len(owned)) as pool:
+            ready = list(pool.map(ready_s, owned))
+        check_owned(config, owned)
+        log(f"shard records={[int(c) for c in owned]} dispatch_s={t_build} "
+            f"built_s={ready}")
+    jax.block_until_ready(eng.shards)
+    log(f"build_s={time.perf_counter() - t} streams_s={t_streams}")
+    return eng, clients, devices
 
 
 def workloads(config: dict, traffic: dict) -> list:
@@ -333,16 +488,25 @@ class TraceView:
     ticks: int                 # engine ticks inside it
     first_tick: int            # engine tick index of the first of them
     engine_spans: list         # (name, start_ns, end_ns), profiler clock
+    device_ids: list | None = None   # the cell's chips; None: every plane
 
     @property
     def window_s(self) -> float:
         return (self.hi_ns - self.lo_ns) * 1e-9
+
+    @property
+    def planes(self) -> list | None:
+        """The trace planes of the cell's chips."""
+        if self.device_ids is None:
+            return None
+        return [devtrace.plane(i) for i in self.device_ids]
 
 
 @dataclasses.dataclass
 class RunRecord:
     cell: Cell
     device_kind: str
+    device_ids: list           # the cell's chips, in shard order
     window_s: float
     window_ticks: int
     window_ops: int
@@ -367,8 +531,9 @@ class Profile:
     spans and the engine's own Tracer spans on."""
 
     def __init__(self, loop: ClosedLoop, tracer, start_s: float,
-                 length_s: float):
+                 length_s: float, device_ids: list):
         self.loop, self.tracer = loop, tracer
+        self.device_ids = device_ids
         self.start_s, self.stop_s = start_s, start_s + length_s
         self.dir = None
         self.state = "before"
@@ -405,8 +570,9 @@ class Profile:
     def view(self) -> TraceView | None:
         if self.state != "done":
             return None
+        planes = [devtrace.plane(i) for i in self.device_ids]
         try:
-            events = devtrace.load(self.dir)
+            events = devtrace.on_planes(devtrace.load(self.dir), planes)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
         win = devtrace.host_spans(events, "perfbench_window")
@@ -417,7 +583,8 @@ class Profile:
         # met the profiler's where the window span began
         spans = [(n, lo + (s - self.us0) * 1e3, lo + (e - self.us0) * 1e3)
                  for n, s, e in engine_spans(self.tracer)]
-        return TraceView(events, lo, hi, self.ticks, self.tick0, spans)
+        return TraceView(events, lo, hi, self.ticks, self.tick0, spans,
+                         self.device_ids)
 
 
 def engine_spans(tracer) -> list:
@@ -433,9 +600,10 @@ def engine_spans(tracer) -> list:
 
 
 def breakdown(view: TraceView) -> dict:
-    """The device operations that took most time, and the device's idle
-    time by what the host was doing meanwhile (the innermost engine phase
-    or harness span around each gap), ten of each."""
+    """The device operations that took most time, summed over the cell's
+    chips, and the time in which all of them were idle by what the host
+    was doing meanwhile (the innermost engine phase or harness span around
+    each gap), ten of each."""
     lo, hi = view.lo_ns, view.hi_ns
     by_op = devtrace.time_by_name(view.events, lo, hi)
     spans = [("engine:" + n, s, e) for n, s, e in view.engine_spans]
@@ -443,7 +611,7 @@ def breakdown(view: TraceView) -> dict:
               for name in ("tick", "submit")
               for e in devtrace.host_spans(view.events, name)]
     idle: dict = {}
-    for g in devtrace.gaps(view.events, lo, hi):
+    for g in devtrace.gaps(view.events, lo, hi, view.planes):
         name = devtrace.name_gap(g, spans, "harness:loop")
         idle[name] = idle.get(name, 0.0) + (g[1] - g[0])
 
@@ -542,22 +710,13 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     """Set up, measure for ``seconds``, check.  Returns (result dict for
     the last line, [(check name, value, limit), ...])."""
     import jax
-    from repro.serving.tracing import Tracer
+    from repro.serving.tracing import COMPILES, Tracer
     cell = load_cell(root, workload)
     enable_cache(root)
-    devices = jax.devices()[:cell.chips]
     config, traffic = cell.config, cell.traffic
     words = seed_words(seed)
-
-    t = time.perf_counter()
-    table = build_table(config, words)           # runs while streams are drawn
     tracer = Tracer(capacity=1 << 21, enabled=False) if trace else None
-    eng, tenants = make_engine(config, traffic, table, tracer)
-    del table
-    clients = make_clients(config, traffic, tenants, seed)
-    t_streams = time.perf_counter() - t
-    jax.block_until_ready(eng.shards)
-    log(f"build_s={time.perf_counter() - t} streams_s={t_streams}")
+    eng, clients, devices = set_up(config, traffic, seed, words, tracer)
     # set-up's objects (the streams above all) live for the whole run: out
     # of the collector's generations, as a server's start-up state would be
     gc.collect()
@@ -573,9 +732,11 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     gc.collect()
     gc.freeze()
 
-    prof = Profile(loop, tracer, seconds / 4, min(3.0, seconds / 2)) \
-        if trace else None
+    device_ids = [d.id for d in devices]
+    prof = Profile(loop, tracer, seconds / 4, min(3.0, seconds / 2),
+                   device_ids) if trace else None
     pauses = GcPauses()
+    compiles0 = COMPILES.counts["compiles"]
     t0 = time.perf_counter()
     setup_s = t0 - t_start
     t_end = t0 + seconds
@@ -594,13 +755,16 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     window_end_tick = eng.ticks - 1
     if prof is not None:
         prof.stop()
-    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-               for d in devices)
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+             for d in devices]
+    peak = max(peaks)
+    log(f"peak_bytes_in_use per chip={peaks}")
     tables_end = list(eng.shards)
     table_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(
         tables_end))
     log(f"window_s={t1 - t0} ticks={loop.ticks} ops={loop.ops} "
-        f"requests={len(loop.latencies)}")
+        f"requests={len(loop.latencies)} "
+        f"compiles={COMPILES.counts['compiles'] - compiles0}")
 
     unanswered = loop.drain()
     t = time.perf_counter()
@@ -610,8 +774,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     for f in res["first"]:
         log(f"wrong answer: {f}")
 
-    rec = RunRecord(cell, devices[0].device_kind, t1 - t0, loop.ticks,
-                    loop.ops, loop.latencies, eng, tables_end)
+    rec = RunRecord(cell, devices[0].device_kind, device_ids, t1 - t0,
+                    loop.ticks, loop.ops, loop.latencies, eng, tables_end)
     metrics: dict = {}
     result_breakdown = None
     if not trace:
@@ -640,7 +804,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
               "memory_peak_bytes": peak}
     if trace and rec.trace is not None:
         v = rec.trace
-        device["busy_s"] = devtrace.busy_ns(v.events, v.lo_ns, v.hi_ns) * 1e-9
+        device["busy_s"] = devtrace.busy_ns(v.events, v.lo_ns, v.hi_ns,
+                                            v.planes) * 1e-9
         device["window_s"] = v.window_s
         result_breakdown = breakdown(v)
 
