@@ -401,6 +401,10 @@ _sharded_call_cache: dict = {}
 
 def _sharded_call(kind: str, mesh, cfg: HashMemConfig, axis: str,
                   shard_by: str, cap):
+    """The jitted shard_map program of ``kind``, named ``<kind>_mesh``, so
+    it traces as ``jit_<kind>_mesh``: ``jit_tick_mesh`` for the fused
+    tick, ``jit_probe_mesh``, ``jit_delete_mesh`` and ``jit_insert_mesh``
+    for the per-phase calls."""
     key = (kind, mesh, cfg, axis, shard_by, cap)
     fn = _sharded_call_cache.get(key)
     if fn is None:
@@ -408,6 +412,7 @@ def _sharded_call(kind: str, mesh, cfg: HashMemConfig, axis: str,
         builder = {"probe": _probe_shard_fn, "delete": _delete_shard_fn,
                    "insert": _insert_shard_fn, "tick": _tick_shard_fn}[kind]
         shard_fn, n_in, n_out = builder(cfg, num_shards, axis, shard_by, cap)
+        shard_fn.__name__ = shard_fn.__qualname__ = f"{kind}_mesh"
         fn = jax.jit(jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(axis),) * n_in,
@@ -524,38 +529,64 @@ def insert_mesh(mesh, hm_stacked, keys, vals, cfg: HashMemConfig,
 # Fused whole-tick megakernel: probe -> delete -> insert in ONE shard_map
 # ---------------------------------------------------------------------------
 
-def routing_cap(keys, cfg: HashMemConfig, num_shards: int,
-                shard_by: str = "mod", *, quantum: int = 8) -> int:
+def route_max(keys, cfg: HashMemConfig, num_shards: int,
+              shard_by: str = "mod") -> int:
     """Pass 1 of the two-pass count+route scheme, host mirror: the max
     per-(src,dst) VALID-key count for a (Q,) batch laid out contiguously
     across ``num_shards`` devices (entries equal to ROUTE_PAD don't count —
-    the fused route drops them).
-
-    The result is rounded up to a multiple of ``quantum`` (bounds the set
-    of compiled capacities to Q_local/quantum per batch shape) and clamped
-    to [min(quantum, Q_local), Q_local].  The ORDER matters: the quantum
-    floor applies first and the Q_local ceiling LAST, so a tiny batch
-    (Q_local < quantum) caps at Q_local — a cap above Q_local would trace
-    an all_to_all buffer larger than the (num_shards, Q_local) source
-    slice.  Rounding is UP, so the capacity can never truncate; on a
-    skewed tick it tracks the measured max instead of the worst-case
-    Q_local the unfused path pads to.
-    """
+    the fused route drops them).  One hash of each key on the host."""
     k = np.asarray(keys, np.uint32)
     q = k.shape[0]
     assert q % num_shards == 0, (q, num_shards)
-    q_local = q // num_shards
     valid = k != ROUTE_PAD
-    mx = 0
-    if valid.any():
-        owner = owner_of_np(k, cfg, num_shards, shard_by)
-        src = np.arange(q) // q_local
-        pair = (src * num_shards + owner)[valid]
-        mx = int(np.bincount(pair, minlength=num_shards * num_shards).max())
+    if not valid.any():
+        return 0
+    owner = owner_of_np(k, cfg, num_shards, shard_by)
+    src = np.arange(q) // (q // num_shards)
+    pair = (src * num_shards + owner)[valid]
+    return int(np.bincount(pair, minlength=num_shards * num_shards).max())
+
+
+def ladder_cap(mx: int, q_local: int) -> int:
+    """The routing capacity for a measured per-(src,dst) max ``mx``: the
+    smallest power of two at or above it, floored at min(8, Q_local) and
+    capped at Q_local.  The floor applies first and the ceiling LAST, so a
+    tiny batch (Q_local < 8) caps at Q_local — a cap above Q_local would
+    trace an all_to_all buffer larger than the (num_shards, Q_local) source
+    slice.  Rounding is up, so the capacity never truncates; the values
+    for one Q_local are :func:`cap_ladder`'s, a set the caller can compile
+    before it routes a single batch."""
+    floor = min(8, q_local)
+    cap = max(floor, 1 << max(mx - 1, 0).bit_length())
+    return min(cap, q_local)
+
+
+def cap_ladder(q_local: int) -> tuple:
+    """Every capacity :func:`ladder_cap` gives for ``q_local``, ascending:
+    the powers of two from min(8, Q_local) below Q_local, then Q_local."""
+    caps, c = [], min(8, q_local)
+    while c < q_local:
+        caps.append(c)
+        c *= 2
+    return tuple(caps) + (q_local,)
+
+
+def routing_cap(keys, cfg: HashMemConfig, num_shards: int,
+                shard_by: str = "mod", *, quantum: Optional[int] = None) \
+        -> int:
+    """The routing capacity of a (Q,) batch from its measured
+    per-(src,dst) max (:func:`route_max`): by default the ladder step
+    (:func:`ladder_cap`), so the capacities a stream can produce are known
+    before it starts.  ``quantum=q`` instead rounds the max up to a
+    multiple of q, clamped to [min(q, Q_local), Q_local]; ``quantum=1`` is
+    the exact max.  On a skewed tick either tracks the measured max instead
+    of the worst-case Q_local the unfused path pads to."""
+    q_local = len(keys) // num_shards
+    mx = route_max(keys, cfg, num_shards, shard_by)
+    if quantum is None:
+        return ladder_cap(mx, q_local)
     cap = max(quantum, -(-mx // quantum) * quantum)
-    cap = min(cap, q_local)                 # ceiling wins over the floor
-    assert cap <= q_local, (cap, q_local)
-    return cap
+    return min(cap, q_local)                # ceiling wins over the floor
 
 
 def _tick_shard_fn(cfg, num_shards, axis, shard_by, caps):
@@ -641,10 +672,17 @@ def tick_mesh(mesh, hm_stacked, probe_q, del_q, ins_k, ins_v,
     table) -> ``delete_sharded`` -> ``insert_mesh`` (against the
     post-delete table) issued back to back.
     """
+    fn = tick_mesh_program(mesh, cfg, axis, caps, shard_by)
+    return fn(hm_stacked, probe_q, del_q, ins_k, ins_v)
+
+
+def tick_mesh_program(mesh, cfg: HashMemConfig, axis: str = "model",
+                      caps=None, shard_by: str = "mod"):
+    """The jitted program behind :func:`tick_mesh` at these ``caps``, for a
+    caller that compiles it ahead of time (``.lower(...).compile()``)."""
     caps = tuple(caps) if caps is not None else (None, None, None)
     assert len(caps) == 3, caps
-    fn = _sharded_call("tick", mesh, cfg, axis, shard_by, caps)
-    return fn(hm_stacked, probe_q, del_q, ins_k, ins_v)
+    return _sharded_call("tick", mesh, cfg, axis, shard_by, caps)
 
 
 def probe_replicated(mesh, hm, queries, cfg: HashMemConfig, axis: str = "data"):

@@ -81,6 +81,7 @@ from typing import Callable, Iterable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import HashMemConfig
 from repro.core import hashmap, rlu
@@ -101,6 +102,8 @@ OP_KINDS = ("read", "update", "insert", "delete", "scan", "rmw")
 # ``route_cap_totals``; the log keeps the most recent launches for stats()
 # and the bench's fill-ratio telemetry without growing with run length)
 ROUTE_CAP_LOG_MAX = 1024
+# the fused tick's phases, in launch order
+ROUTE_PHASES = ("probe", "delete", "insert")
 
 # slow-tick record (``stats()["slow_ticks"]``): a tick longer than
 # SLOW_TICK_FACTOR x the running median tick is kept, with its phases, in a
@@ -447,7 +450,8 @@ class _MeshShards:
     is_mesh = True
 
     def __init__(self, eng: "ServingEngine", mesh, axis: str,
-                 cfg: HashMemConfig, tables: Optional[list] = None):
+                 cfg: HashMemConfig, max_slots: int,
+                 tables: Optional[list] = None):
         # shard d is built, grown and compacted on device d; the stacked
         # pytree is assembled from the per-device pieces
         self._place = lambda shards: rlu.place_shards(mesh, shards, axis)
@@ -456,6 +460,15 @@ class _MeshShards:
         self.axis = axis
         self.num_shards = mesh.shape[axis]
         self.cfg = cfg
+        # the fused tick's phase length: a phase without scans holds at most
+        # one key per slot, so every such tick fits one power of two, split
+        # evenly over the shards
+        self.tick_len = self._split(1 << max(max_slots - 1, 0).bit_length())
+        self._batch = NamedSharding(mesh, PartitionSpec(axis))
+        # fused tick programs by (table avals, phase length, caps), compiled
+        # ahead of time and called directly; the ladder set is compiled by
+        # compile_ticks before the first tick
+        self._ticks: dict = {}
         if tables is None:
             tables = []
             for d in mesh.devices.reshape(-1):
@@ -486,35 +499,75 @@ class _MeshShards:
             return np.asarray(v)[:len(keys)], np.asarray(f)[:len(keys)]
         return finalize
 
+    def _split(self, n: int) -> int:
+        """``n`` rounded up to a multiple of the shard count."""
+        return -(-n // self.num_shards) * self.num_shards
+
+    def _tick_program(self, n: int, caps: tuple):
+        """The fused tick compiled for phases of ``n`` keys at ``caps``, and
+        the XLA compiles it took (none where the process holds it)."""
+        key = (self.cfg, n, caps)
+        prog = self._ticks.get(key)
+        if prog is not None:
+            return prog, 0
+        compiles0 = COMPILES.counts["compiles"]
+        sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,  # noqa: E731
+                                             sharding=x.sharding)
+        q = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=self._batch)
+        prog = rlu.tick_mesh_program(
+            self.mesh, self.cfg, self.axis, caps, self.eng.shard_by).lower(
+                jax.tree.map(sds, self.hm_stacked), q, q, q, q).compile()
+        self._ticks[key] = prog
+        return prog, COMPILES.counts["compiles"] - compiles0
+
+    def compile_ticks(self) -> int:
+        """Compile the fused tick for every capacity the ladder gives at
+        ``tick_len``: (probe cap, write cap) over ``rlu.cap_ladder`` of
+        tick_len / num_shards each, so no tick of up to ``tick_len`` keys a
+        phase compiles once serving starts.  Returns the programs held."""
+        ladder = rlu.cap_ladder(self.tick_len // self.num_shards)
+        for cp in ladder:
+            for cw in ladder:
+                self._tick_program(self.tick_len, (cp, cw, cw))
+        return len(ladder) ** 2
+
     def tick_fused(self, pk, dk, ik, iv):
         """The whole tick's three phases in ONE rlu.tick_mesh shard_map
-        launch, routed at two-pass skew-aware capacities.  Empty phases ride
-        along as (num_shards,) all-pad placeholders (their capacity is the
-        quantum floor); returns one finalize per phase with the same
-        contracts as probe/delete/insert."""
+        launch, routed at two-pass skew-aware capacities.  Every phase is
+        padded to one length, ``tick_len`` unless scans make a phase longer
+        (then the power of two above it); empty phases ride along all-pad.
+        The probe phase's capacity is the ladder step
+        (``rlu.ladder_cap``) of its measured per-(src,dst) max, and the
+        delete and insert phases share the step of the larger of theirs, so
+        a tick at ``tick_len`` runs one of the programs ``compile_ticks``
+        compiled.  Returns one finalize per phase with the same contracts
+        as probe/delete/insert."""
+        m = max(len(pk), len(dk), len(ik))
+        n = self.tick_len if m <= self.tick_len else \
+            self._split(1 << (m - 1).bit_length())
+
         def prep(arr, fill):
-            if len(arr) == 0:
-                return np.full(self.num_shards, fill, np.uint32)
-            return self.eng._padded(np.asarray(arr, np.uint32), fill)
+            q = np.full(n, fill, np.uint32)
+            q[:len(arr)] = arr
+            return q
         q_p, q_d, q_k = prep(pk, PAD_KEY), prep(dk, PAD_KEY), prep(ik, PAD_KEY)
-        q_v = prep(iv, np.uint32(0)) if len(ik) else \
-            np.zeros(self.num_shards, np.uint32)
+        q_v = prep(iv, 0)
+        ql = n // self.num_shards
         # pass 1 (host mirror of the on-device count exchange): measured
         # per-(src,dst) maxima set the routing capacities for pass 2
-        caps, meas = [], []
         with self.eng._phase_span("route", self.eng._cur_lane):
-            for q in (q_p, q_d, q_k):
-                caps.append(rlu.routing_cap(q, self.cfg, self.num_shards,
-                                            self.eng.shard_by))
-                meas.append(rlu.routing_cap(q, self.cfg, self.num_shards,
-                                            self.eng.shard_by, quantum=1))
-        self.hm_stacked, v, f, df, iok = rlu.tick_mesh(
-            self.mesh, self.hm_stacked, jnp.asarray(q_p), jnp.asarray(q_d),
-            jnp.asarray(q_k), jnp.asarray(q_v), self.cfg, self.axis,
-            caps=tuple(caps), shard_by=self.eng.shard_by)
+            meas = [rlu.route_max(q, self.cfg, self.num_shards,
+                                  self.eng.shard_by) for q in (q_p, q_d, q_k)]
+        cw = rlu.ladder_cap(max(meas[1], meas[2]), ql)
+        caps = (rlu.ladder_cap(meas[0], ql), cw, cw)
+        prog, compiled = self._tick_program(n, caps)
+        self.hm_stacked, v, f, df, iok = prog(
+            self.hm_stacked, *jax.device_put((q_p, q_d, q_k, q_v),
+                                             self._batch))
         self.eng._record_call("fused_tick")
-        self.eng._record_route_caps(
-            [len(q) // self.num_shards for q in (q_p, q_d, q_k)], caps, meas)
+        self.eng._record_route_caps([ql] * 3, caps, meas,
+                                    (len(pk), len(dk), len(ik)))
+        self.eng.route_cap_totals["tick_programs_after"] += compiled
         owners_d = self.owners(dk) if len(dk) else np.zeros(0, np.int32)
 
         def fin_probe():
@@ -727,7 +780,8 @@ class ServingEngine:
         self._jit_insert = _jitted("insert")
         if mesh is not None:
             base = tables[0].config if tables else cfg
-            self.backend = _MeshShards(self, mesh, mesh_axis, base, tables)
+            self.backend = _MeshShards(self, mesh, mesh_axis, base,
+                                       max_slots, tables)
         elif tables is not None:
             self.backend = _HostShards(self, list(tables), borrowed=True)
         else:
@@ -754,8 +808,16 @@ class ServingEngine:
         # capacity tracking skew instead of the Q_local worst case.  The
         # log is a bounded ring; exact lifetime sums live in the totals.
         self.route_cap_log: deque = deque(maxlen=ROUTE_CAP_LOG_MAX)
+        # per phase, the routed buffer rows (all chips' send buffers) and
+        # the real keys in them; and the fused tick programs compiled
+        # before the first tick and after it
         self.route_cap_totals = {"launches": 0, "cap_sum": 0,
-                                 "q_local_sum": 0, "measured_sum": 0}
+                                 "q_local_sum": 0, "measured_sum": 0,
+                                 "tick_programs_before": 0,
+                                 "tick_programs_after": 0}
+        for ph in ROUTE_PHASES:
+            self.route_cap_totals[ph + "_rows"] = 0
+            self.route_cap_totals[ph + "_keys"] = 0
         self._tombstones = [0] * self.num_shards
         self.grow_events = 0
         # extendible-resize telemetry: group splits and directory doublings
@@ -785,6 +847,11 @@ class ServingEngine:
         self._tick_phases: dict = {}
         self._tick_p50 = 0.0
         self.slow_ticks: deque = deque(maxlen=SLOW_TICK_LOG_MAX)
+        if self.fused_tick:
+            # every program a tick of up to max_slots ops can need, now: a
+            # compile inside serving stalls every request in flight
+            self.route_cap_totals["tick_programs_before"] = \
+                self.backend.compile_ticks()
 
     # -- back-compat views -------------------------------------------------
     @property
@@ -983,7 +1050,10 @@ class ServingEngine:
         tracing is off."""
         return _PhaseTimer(self, name, lane, args)
 
-    def _record_route_caps(self, q_locals, caps, measured):
+    def _record_route_caps(self, q_locals, caps, measured, keys=(0, 0, 0)):
+        """Log one fused launch: per phase (probe, delete, insert) its
+        Q_local, routed capacity, measured per-(src,dst) max and real
+        keys."""
         self.route_cap_log.append({
             "tick": self.ticks,
             "q_local": list(q_locals),     # worst-case (unfused) capacity
@@ -995,14 +1065,20 @@ class ServingEngine:
         tot["cap_sum"] += int(sum(caps))
         tot["q_local_sum"] += int(sum(q_locals))
         tot["measured_sum"] += int(sum(measured))
+        # each phase's exchange carries D*cap rows per device across D
+        # devices
+        rows = [self.num_shards ** 2 * int(c) for c in caps]
+        for ph, r, k in zip(ROUTE_PHASES, rows, keys):
+            tot[ph + "_rows"] += r
+            tot[ph + "_keys"] += int(k)
         tr = self.tracer
         if tr.enabled:
             ql = sum(q_locals)
             tr.counter("route_cap_fill", sum(caps) / ql if ql else 1.0)
-            # routed all_to_all element volume this launch: each phase's
-            # exchange carries D*cap elements per device across D devices
-            tr.counter("routed_elems",
-                       self.num_shards ** 2 * int(sum(caps)))
+            # routed all_to_all element volume this launch, and the real
+            # keys in it
+            tr.counter("routed_elems", sum(rows))
+            tr.counter("routed_keys", int(sum(keys)))
 
     # -- the tick ----------------------------------------------------------
     def tick(self) -> int:
